@@ -5,7 +5,10 @@ KV memory — exactly the paper's Figure 1 unit op. On TPU the op is
 HBM-bandwidth-bound (the KV cache streams through VMEM once), so the
 MXU-friendly layout puts the GQA *query-head group* in the sublane
 dimension: each grid step computes a [G, bk] score tile with one
-[G, D]·[D, bk] matmul.
+[G, D]·[D, bk] matmul. q, mask and output are viewed as
+``[B, Hkv, G, ·]`` so every block's last two dims are either the full
+array dims (G, D) or lane-aligned (bk) — the TPU compiler's tiling rule;
+blocking G rows out of a ``[B, Hq, ·]`` array is refused for G < 8.
 
 A³ enters as a per-position candidate mask (row-granular — decode is
 bandwidth- not MXU-bound, so row granularity costs nothing here) plus the
@@ -50,9 +53,9 @@ def _rowmax_kernel(q_ref, k_ref, mask_ref, m_out, m_scr, *, scale):
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
 
-    q = q_ref[0].astype(jnp.float32)                     # [G, D]
+    q = q_ref[0, 0].astype(jnp.float32)                  # [G, D]
     k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
-    mask = mask_ref[0]                                   # [G, bk]
+    mask = mask_ref[0, 0]                                # [G, bk]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = jnp.where(mask, s, NEG_INF)
@@ -60,7 +63,7 @@ def _rowmax_kernel(q_ref, k_ref, mask_ref, m_out, m_scr, *, scale):
 
     @pl.when(ik == nk - 1)
     def _emit():
-        m_out[0] = m_scr[...][:, 0]
+        m_out[0, 0] = m_scr[...]
 
 
 def _attend_kernel(q_ref, k_ref, v_ref, mask_ref, rm_ref, o_ref,
@@ -73,11 +76,11 @@ def _attend_kernel(q_ref, k_ref, v_ref, mask_ref, rm_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                     # [G, D]
+    q = q_ref[0, 0].astype(jnp.float32)                  # [G, D]
     k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
     v = v_ref[0, 0].astype(jnp.float32)                  # [bk, Dv]
-    mask = mask_ref[0]                                   # [G, bk]
-    rm = rm_ref[0][:, None]                              # [G, 1]
+    mask = mask_ref[0, 0]                                # [G, bk]
+    rm = rm_ref[0, 0]                                    # [G, 1]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if threshold is not None:
@@ -91,8 +94,8 @@ def _attend_kernel(q_ref, k_ref, v_ref, mask_ref, rm_ref, o_ref,
     def _emit():
         l = l_scr[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = jnp.where(l == 0.0, 0.0, acc_scr[...] / safe
-                             ).astype(o_ref.dtype)
+        o_ref[0, 0] = jnp.where(l == 0.0, 0.0, acc_scr[...] / safe
+                                ).astype(o_ref.dtype)
 
 
 def _fused_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
@@ -109,10 +112,10 @@ def _fused_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                     # [G, D]
+    q = q_ref[0, 0].astype(jnp.float32)                  # [G, D]
     k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
     v = v_ref[0, 0].astype(jnp.float32)                  # [bk, Dv]
-    mask = mask_ref[0]                                   # [G, bk]
+    mask = mask_ref[0, 0]                                # [G, bk]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = jnp.where(mask, s, NEG_INF)
@@ -132,8 +135,8 @@ def _fused_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
     def _emit():
         l = l_scr[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = jnp.where(l == 0.0, 0.0, acc_scr[...] / safe
-                             ).astype(o_ref.dtype)
+        o_ref[0, 0] = jnp.where(l == 0.0, 0.0, acc_scr[...] / safe
+                                ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -161,50 +164,59 @@ def decode_attention(
     assert s % bk == 0
 
     grid = (b, hkv, s // bk)
+    qg = q.reshape(b, hkv, group, d)
+    maskg = mask.reshape(b, hkv, group, s)
 
-    q_spec = pl.BlockSpec((1, group, d), lambda b_, h, ik: (b_, h, 0))
+    q_spec = pl.BlockSpec((1, 1, group, d),
+                          lambda b_, h, ik: (b_, h, 0, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h, ik: (b_, h, ik, 0))
     vv_spec = pl.BlockSpec((1, 1, bk, dv), lambda b_, h, ik: (b_, h, ik, 0))
-    mask_spec = pl.BlockSpec((1, group, bk), lambda b_, h, ik: (b_, h, ik))
-    o_spec = pl.BlockSpec((1, group, dv), lambda b_, h, ik: (b_, h, 0))
+    mask_spec = pl.BlockSpec((1, 1, group, bk),
+                             lambda b_, h, ik: (b_, h, 0, ik))
+    o_spec = pl.BlockSpec((1, 1, group, dv),
+                          lambda b_, h, ik: (b_, h, 0, 0))
+    o_shape = jax.ShapeDtypeStruct((b, hkv, group, dv), q.dtype)
 
     if not exact_two_pass:
-        return pl.pallas_call(
+        out = pl.pallas_call(
             functools.partial(_fused_kernel, scale=scale,
                               threshold=threshold),
             grid=grid,
             in_specs=[q_spec, kv_spec, vv_spec, mask_spec],
             out_specs=o_spec,
-            out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
+            out_shape=o_shape,
             scratch_shapes=[
                 pltpu.VMEM((group, 1), jnp.float32),
                 pltpu.VMEM((group, 1), jnp.float32),
                 pltpu.VMEM((group, dv), jnp.float32),
             ],
             interpret=interpret,
-        )(q, k, v, mask)
+        )(qg, k, v, maskg)
+        return out.reshape(b, hq, dv)
 
-    rm_spec = pl.BlockSpec((1, group), lambda b_, h, ik: (b_, h))
+    rm_spec = pl.BlockSpec((1, 1, group, 1),
+                           lambda b_, h, ik: (b_, h, 0, 0))
 
     rowmax = pl.pallas_call(
         functools.partial(_rowmax_kernel, scale=scale),
         grid=grid,
         in_specs=[q_spec, kv_spec, mask_spec],
         out_specs=rm_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((group, 1), jnp.float32)],
         interpret=interpret,
-    )(q, k, mask)
+    )(qg, k, maskg)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_attend_kernel, scale=scale, threshold=threshold),
         grid=grid,
         in_specs=[q_spec, kv_spec, vv_spec, mask_spec, rm_spec],
         out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
+        out_shape=o_shape,
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, dv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, mask, rowmax)
+    )(qg, k, v, maskg, rowmax)
+    return out.reshape(b, hq, dv)

@@ -10,16 +10,10 @@ see 1 device).
 from __future__ import annotations
 
 import jax
-
-try:                                   # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: Auto is the only type
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes) -> jax.sharding.Mesh:
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

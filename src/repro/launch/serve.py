@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro.config import A3Config, ServeConfig, get_arch, smoke_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import decoder
 from repro.serve.chaos import ChaosConfig, ChaosInjector
 from repro.serve.engine import ServeEngine
@@ -147,6 +148,7 @@ def main() -> None:
                     choices=["off", "conservative", "aggressive"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

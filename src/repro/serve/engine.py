@@ -535,9 +535,9 @@ class _PendingHarvest:
 
 
 class ServeEngine:
-    """Slot-based batched serving. Single-host reference implementation —
-    the sharded path reuses make_serve_step / make_prefill_chunk_step
-    under a mesh (launch.serve)."""
+    """Slot-based batched serving on one device. There is no multi-chip
+    serving path: ``launch.serve`` builds no mesh, and ``launch.dryrun``
+    only compiles the decoder's step functions for simulated meshes."""
 
     def __init__(self, params: Any, cfg: ModelConfig, *, slots: int = 4,
                  max_len: int = 2048, a3: A3Config = A3Config(),

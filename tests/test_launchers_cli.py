@@ -12,9 +12,11 @@ SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "src")
 
 
-def _run(args, timeout=900):
+def _run(args, cache_dir, timeout=900):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    # keep the launcher's persistent compile cache out of the checkout
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir / "jax_cache")
     return subprocess.run([sys.executable, "-m"] + args, env=env,
                           capture_output=True, text=True, timeout=timeout)
 
@@ -23,17 +25,17 @@ def _run(args, timeout=900):
 def test_train_cli_smoke(tmp_path):
     p = _run(["repro.launch.train", "--arch", "internlm2-1.8b", "--smoke",
               "--steps", "6", "--batch", "2", "--seq", "64",
-              "--ckpt-dir", str(tmp_path), "--save-every", "3"])
+              "--ckpt-dir", str(tmp_path), "--save-every", "3"], tmp_path)
     assert p.returncode == 0, p.stderr[-2000:]
     assert "loss" in p.stdout
     assert any(d.startswith("step_") for d in os.listdir(tmp_path))
 
 
 @pytest.mark.slow
-def test_serve_cli_smoke_with_a3():
+def test_serve_cli_smoke_with_a3(tmp_path):
     p = _run(["repro.launch.serve", "--arch", "phi4-mini-3.8b", "--smoke",
               "--requests", "2", "--prompt-len", "12", "--max-new", "4",
-              "--max-len", "64", "--a3", "conservative"])
+              "--max-len", "64", "--a3", "conservative"], tmp_path)
     assert p.returncode == 0, p.stderr[-2000:]
     assert "requests=2/2" in p.stdout
 
@@ -47,7 +49,7 @@ def test_serve_cli_checkpoint_then_restore(tmp_path):
     p = _run(["repro.launch.serve", "--arch", "phi4-mini-3.8b", "--smoke",
               "--requests", "2", "--prompt-len", "12", "--max-new", "4",
               "--max-len", "64", "--cache-pages", "8", "--page-size", "8",
-              "--l2-bytes", str(1 << 24), "--checkpoint-dir", ck])
+              "--l2-bytes", str(1 << 24), "--checkpoint-dir", ck], tmp_path)
     assert p.returncode == 0, p.stderr[-2000:]
     assert "requests=2/2" in p.stdout
     assert "checkpointed engine" in p.stdout
@@ -55,15 +57,15 @@ def test_serve_cli_checkpoint_then_restore(tmp_path):
     p2 = _run(["repro.launch.serve", "--arch", "phi4-mini-3.8b", "--smoke",
                "--requests", "1", "--prompt-len", "12", "--max-new", "4",
                "--max-len", "64",
-               "--checkpoint-dir", ck, "--restore"])
+               "--checkpoint-dir", ck, "--restore"], tmp_path)
     assert p2.returncode == 0, p2.stderr[-2000:]
     assert "restored engine" in p2.stdout
     assert "requests=1/1" in p2.stdout
 
 
 @pytest.mark.slow
-def test_dryrun_cli_list():
-    p = _run(["repro.launch.dryrun", "--list"], timeout=300)
+def test_dryrun_cli_list(tmp_path):
+    p = _run(["repro.launch.dryrun", "--list"], tmp_path, timeout=300)
     assert p.returncode == 0, p.stderr[-2000:]
     assert "grok-1-314b" in p.stdout and "long_500k" in p.stdout
 
@@ -81,7 +83,7 @@ def test_serve_cli_telemetry_artifacts(tmp_path):
               "--max-len", "64", "--a3", "conservative",
               "--decode-block", "2", "--telemetry-every", "1",
               "--stats-json", stats, "--metrics-json", metrics,
-              "--trace-out", trace])
+              "--trace-out", trace], tmp_path)
     assert p.returncode == 0, p.stderr[-2000:]
     assert "requests=2/2" in p.stdout
     with open(stats) as f:
