@@ -1,0 +1,332 @@
+"""CPU tests of the chip benchmark: the trace reduction, the traffic
+generator, the readers' arithmetic, lookup by name, the refusal off the
+TPU, the work counts, and whole tiny runs in which ``correct`` holds for
+the program and fails for the control and for a broken timed path.
+
+    JAX_PLATFORMS=cpu python3 -m pytest -q chipbench/tests
+"""
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(ROOT))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
+
+from chipbench import harness, traffic  # noqa: E402
+from chipbench import tracereduce as TR  # noqa: E402
+from chipbench.readout import Req, Run, Tick, itl_samples, percentile  # noqa: E402
+from chipbench.workcount import Work  # noqa: E402
+
+import tiny  # noqa: E402
+
+# limits of the small test cell, set between the program's readings (widest
+# gap at most 0.015, mean at most 3.0e-4 over 6 runs) and the fp8
+# control's (at least 0.19 and 0.014, 3 runs) at this size, on the CPU
+TINY_LIMITS = {"logit_gap": 0.05, "mean_logit_gap": 0.002}
+
+
+# -- trace reduction ----------------------------------------------------------
+
+def _ev(name, start, dur):
+    return TR.Event(name, float(start), float(dur))
+
+
+def test_trace_reduction_matches_programs_and_idle():
+    dev = "/device:TPU:0"
+    modules = [_ev("jit_step(7)", 100, 50), _ev("jit_step(9)", 160, 20),
+               _ev("jit_convert", 185, 2), _ev("jit_step(9)", 300, 20)]
+    ops = [_ev("fusion.1", 100, 30), _ev("fusion.2", 130, 20),
+           _ev("fusion.3", 160, 20), _ev("copy", 185, 2),
+           _ev("fusion.3", 300, 20), _ev("late", 450, 100)]
+    host = [_ev("chipbench.window", 90, 310), _ev("chipbench.step", 95, 100),
+            _ev("chipbench.observe", 195, 10), _ev("chipbench.wait", 205, 90),
+            _ev("chipbench.step", 295, 30)]
+    ev = TR.TraceEvents({dev: modules}, {dev: ops}, host)
+    red = TR.reduce(ev, ["prefill", "decode", "decode"])
+    assert red.matched
+    assert red.programs == [("prefill", 50e-9), ("decode", 20e-9),
+                            ("decode", 20e-9)]
+    assert red.window_s == pytest.approx(310e-9)
+    # busy: [100,150) + [160,180) + [185,187) + [300,320) in [90, 400)
+    assert red.busy_s == pytest.approx(92e-9)
+    # each idle gap goes to the host span that holds its midpoint
+    idle = dict(red.idle_gaps)
+    assert idle["step"] == pytest.approx(25e-9)       # 90-100, 150-160, 180-185
+    assert idle["wait"] == pytest.approx(113e-9)      # 187-300
+    assert idle["other"] == pytest.approx(80e-9)      # 320-400
+    assert "observe" not in idle
+    ops_by = dict(red.device_ops)
+    assert ops_by["decode:fusion.3"] == pytest.approx(40e-9)
+    assert ops_by["prefill:fusion.1"] == pytest.approx(30e-9)
+    assert "other:late" not in ops_by                            # after the window
+
+
+def test_trace_reduction_refuses_to_guess_on_mismatch():
+    dev = "/device:TPU:0"
+    ev = TR.TraceEvents({dev: [_ev("jit_step", 10, 5)]}, {},
+                        [_ev("chipbench.window", 0, 100)])
+    red = TR.reduce(ev, ["prefill", "decode"])
+    assert not red.matched and red.programs == []
+    assert red.busy_s == pytest.approx(5e-9)
+
+
+def test_union_and_gaps():
+    u = TR.union([(5, 10), (0, 3), (2, 4), (9, 12)], 1, 11)
+    assert u == [(1, 4), (5, 11)]
+    assert TR.gaps(u, 0, 20) == [(0, 1), (4, 5), (11, 20)]
+
+
+# -- traffic -------------------------------------------------------------------
+
+@pytest.mark.parametrize("mix", ["chat-poisson"])
+def test_generator_is_deterministic_by_seed(mix):
+    m = traffic.load_mix(mix)
+    big = 2 ** 31 + 12345
+    a = traffic.generate(m, big, 10, 1000)
+    b = traffic.generate(m, big, 10, 1000)
+    c = traffic.generate(m, big + 1, 10, 1000)
+    assert [r.due for r in a] == [r.due for r in b]
+    assert all(np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
+    # another seed: another order and other tokens, the same sizes
+    assert sorted((len(r.prompt), r.max_new) for r in a) == \
+        sorted((len(r.prompt), r.max_new) for r in c)
+    assert [len(r.prompt) for r in a] != [len(r.prompt) for r in c]
+    lo, hi = m["prompt"]["min"], m["prompt"]["max"]
+    assert all(lo <= len(r.prompt) <= hi for r in a)
+    assert all(len(r.prompt) + r.max_new - 1 <= m["max_len"] for r in a)
+    if m["loop"] == "open":
+        gaps = np.diff([0.0] + [r.due for r in a])
+        assert sorted(gaps) == pytest.approx(
+            sorted(np.diff([0.0] + [r.due for r in c])))
+        assert np.mean(gaps) == pytest.approx(1 / m["rate_per_s"], rel=0.15)
+
+
+def test_lognormal_quantiles_have_the_stated_median():
+    spec = {"dist": "lognormal", "median": 320, "sigma": 0.7,
+            "min": 32, "max": 768}
+    x = traffic.quantile_lengths(spec, 1001)
+    assert np.median(x) == 320 and x.min() >= 32 and x.max() == 768
+
+
+# -- readers -------------------------------------------------------------------
+
+def _run(requests, ticks=(), loop="open"):
+    return Run(t0=10.0, t1=20.0, setup_s=3.0, loop=loop,
+               requests=list(requests), ticks=list(ticks),
+               work=Work(json.loads((ROOT / "chipbench/configs/"
+                                     "phi4-mini-3.8b.json").read_text()), 1024),
+               peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+
+
+def test_percentile_is_over_all_samples():
+    rng = np.random.default_rng(0)
+    for n in (1, 2, 7, 100, 1001):
+        v = rng.exponential(size=n).tolist()
+        for q in (50, 90, 99):
+            assert percentile(v, q) == pytest.approx(np.percentile(v, q))
+    assert percentile([], 50) is None
+
+
+def test_rates_and_tails_use_the_whole_window():
+    reqs = [Req(prompt_len=10, max_new=4, due=9.0, sent=9.5,
+                token_times=[9.9, 10.5, 11.0, 21.0]),
+            Req(prompt_len=10, max_new=3, due=12.0, sent=12.0,
+                token_times=[12.4, 12.9, 19.9])]
+    run = _run(reqs)
+    out = harness.load_reader("output_tok_s")(run)
+    assert out == pytest.approx(5 / 10.0)      # 5 tokens inside [10, 20]
+    assert sorted(itl_samples(run)) == pytest.approx([0.5, 0.5, 0.6, 7.0])
+    assert harness.load_reader("itl_p99_ms")(run) == pytest.approx(
+        np.percentile([0.6, 0.5, 0.5, 7.0], 99) * 1e3)
+    # only the request whose first token arrived in the window, from due
+    assert harness.load_reader("ttft_p50_ms")(run) == pytest.approx(400.0)
+    closed = _run(reqs, loop="closed")
+    assert harness.load_reader("ttft_p90_ms")(closed) == pytest.approx(400.0)
+
+
+def test_scheduler_and_device_readers():
+    ticks = [Tick(10.0, 10.1, 1, 1, [(0, 300, True)], 1, 1, [301]),
+             Tick(10.1, 10.2, 0, 1, [], 1, 1, [302, 40]),
+             Tick(10.2, 10.3, 0, 1, [], 1, 1, [303, 41])]
+    run = _run([], ticks)
+    assert harness.load_reader("decode_occupancy")(run) == pytest.approx(5 / 3)
+    run.trace = TR.Reduced(window_s=0.5, busy_s=0.4,
+                           programs=[("prefill", 0.2), ("decode", 0.02),
+                                     ("decode", 0.02), ("decode", 0.02)],
+                           matched=True, device_ops=[], idle_gaps=[])
+    assert harness.load_reader("decode_step_ms")(run) == pytest.approx(20.0)
+    assert harness.load_reader("prefill_ms_per_ktok")(run) == pytest.approx(
+        200.0 / 0.3)
+    assert harness.load_reader("device_idle_share")(run) == pytest.approx(20.0)
+    roof = harness.load_reader("decode_roofline")(run)
+    w = run.work
+    least = sum(w.decode_step_least_s(1, t.decode_keys, run.peaks)
+                for t in ticks)
+    assert roof == pytest.approx(100 * least / 0.06)
+    assert 0 < roof <= 100
+    mfu = harness.load_reader("serve_mfu")(run)
+    flops = w.prefill_flops(0, 300, True) + sum(
+        w.decode_lane_flops(k) for t in ticks for k in t.decode_keys)
+    assert mfu == pytest.approx(100 * flops / 0.5 / 197e12)
+    run.trace = None
+    assert harness.load_reader("decode_step_ms")(run) is None
+
+
+# -- lookup by name ------------------------------------------------------------
+
+def test_cells_configs_traffic_and_metrics_are_found_by_name():
+    bench = harness.load_benchmark()
+    for w in bench["workloads"]:
+        cell = harness.find_cell(bench, w["name"])
+        assert cell.config["name"] == w["config"]
+        assert cell.mix == traffic.load_mix(w["traffic"])
+        assert cell.end_to_end and cell.per_layer
+        assert any(m["name"] == "setup_s" for m in cell.end_to_end)
+        for m in cell.end_to_end + cell.per_layer:
+            assert callable(harness.load_reader(m["name"]))
+    with pytest.raises(KeyError):
+        harness.find_cell(bench, "no-such-cell")
+    with pytest.raises(FileNotFoundError):
+        traffic.load_mix("no-such-mix")
+    with pytest.raises(FileNotFoundError):
+        harness.load_reader("no_such_metric")
+
+
+def test_benchmark_file_keeps_to_its_shape():
+    bench = harness.load_benchmark()
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
+    assert len(names) == len(set(names))
+    e2e = {m["name"] for m in bench["end_to_end"]}
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["per_layer"]:
+        assert m["moves"] in e2e
+        assert set(m.get("workloads", cells)) <= cells
+    for c in bench["configs"]:
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+
+
+# -- refusal off the TPU -------------------------------------------------------
+
+def test_run_refuses_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "chipbench/run.py", "--workload",
+         "phi4-chat-poisson", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert "{" not in p.stdout
+    assert "needs 1 TPU" in p.stderr
+
+
+def test_run_refuses_without_the_program(tmp_path):
+    import shutil
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns(".out", "__pycache__"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "chipbench/run.py", "--workload",
+         "phi4-chat-poisson", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert "{" not in p.stdout
+    assert "not in this checkout" in p.stderr
+
+
+# -- work counts ---------------------------------------------------------------
+
+def _work(name, ring):
+    return Work(json.loads((ROOT / f"chipbench/configs/{name}.json")
+                           .read_text()), ring)
+
+
+def test_work_counts_match_hand_numbers():
+    phi = _work("phi4-mini-3.8b", 1024)
+    assert phi.params() == 4_450_618_368
+    assert phi.weight_bytes() == 8_901_236_736
+    assert phi.kv_bytes_per_token() == 131_072          # 32 x 2 x 8 x 128 x 2
+    assert phi.step_weight_bytes() == 8_901_236_736 - 200064 * 3072 * 2
+    assert phi.decode_lane_bytes(1000) == 1000 * 131_072
+    # 2 x params per token, and causal attention keys 1 + 2 + ... + n
+    assert phi.prefill_flops(0, 2, False) == (
+        2 * 32 * phi.layer_params() * 2 + 32 * 4 * 24 * 128 * 3)
+
+
+# -- whole tiny runs on the CPU -------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tiny_arch():
+    tiny.register_tiny_arch()
+
+
+def _tiny(loop="closed"):
+    t = time.perf_counter()
+    return harness.run_cell(tiny.tiny_cell(loop, TINY_LIMITS), 2 ** 33 + 5,
+                            3.0, False, t, control=True)
+
+
+def test_tiny_run_is_correct(tiny_arch):
+    out = _tiny("open")
+    assert out.correct, out.compared
+    assert out.failed == 0 and out.attempted > 0
+    assert set(out.metrics) >= {"output_tok_s", "itl_p99_ms", "setup_s"}
+    assert out.diagnostics["window_compiles"]["backend_compiles"] == 0
+    for name, limit in TINY_LIMITS.items():
+        assert 0 <= out.compared[name]["value"] <= limit
+
+
+def test_cell_without_limits_is_not_correct(tiny_arch):
+    t = time.perf_counter()
+    out = harness.run_cell(tiny.tiny_cell("closed", None), 2 ** 33 + 6, 3.0,
+                           False, t)
+    assert not out.correct and out.compared == {}
+    assert set(out.diagnostics["gaps"]) == set(TINY_LIMITS)
+
+
+def test_control_fails(tiny_arch):
+    d = _tiny().diagnostics
+    assert any(d["control_gaps"][name] > limit
+               for name, limit in TINY_LIMITS.items())
+
+
+def _broken_decode_state(decoder):
+    real = decoder.decode_step
+
+    def step(params, cfg, cache, *a, **kw):
+        out = real(params, cfg, cache, *a, **kw)
+        return (out[0], cache) + tuple(out[2:])
+    return step
+
+
+def _altered_token(decoder):
+    real = decoder.sample_logits
+
+    def sample(logits, **kw):
+        return (real(logits, **kw) + 1) % logits.shape[-1]
+    return sample
+
+
+@pytest.mark.parametrize("fault", ["decode_state_unchanged",
+                                   "token_altered"])
+def test_broken_timed_path_is_not_correct(tiny_arch, monkeypatch, fault):
+    from repro.models import decoder
+    if fault == "decode_state_unchanged":
+        monkeypatch.setattr(decoder, "decode_step",
+                            _broken_decode_state(decoder))
+    else:
+        monkeypatch.setattr(decoder, "sample_logits", _altered_token(decoder))
+    out = _tiny()
+    assert not out.correct
+    assert out.compared["logit_gap"]["value"] > TINY_LIMITS["logit_gap"]
