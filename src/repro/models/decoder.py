@@ -140,6 +140,7 @@ def _moe_cfg(cfg: ModelConfig):
     return m
 
 
+@jax.named_scope("ffn")
 def _ffn_block(lp: Params, h: jax.Array, cfg: ModelConfig,
                seg: SegmentSpec) -> Tuple[jax.Array, jax.Array]:
     """Kind-independent FFN half of a block. Returns (h, moe_aux_loss)."""
@@ -190,6 +191,7 @@ def embed_tokens(params: Params, cfg: ModelConfig, tokens: jax.Array
     return h * jnp.asarray(math.sqrt(cfg.d_model), h.dtype)
 
 
+@jax.named_scope("lm_head")
 def unembed(params: Params, cfg: ModelConfig, h: jax.Array) -> jax.Array:
     h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
     if cfg.tie_embeddings:
@@ -442,6 +444,7 @@ def decode_step(
 # multi-step scanned decode: T steps per dispatch, sampling in-graph
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("a3_resort")
 def resort_sorted_keys(cache: Dict[str, Any], pos: jax.Array,
                        resort_every: int) -> Dict[str, Any]:
     """In-graph A^3 re-sort: fold each lane's ring into its sorted key
@@ -499,6 +502,7 @@ def resort_sorted_keys(cache: Dict[str, Any], pos: jax.Array,
 POISON = -2
 
 
+@jax.named_scope("sample")
 def sample_logits(logits: jax.Array, *, temperature: float = 0.0,
                   rng: Optional[jax.Array] = None,
                   pos: Optional[jax.Array] = None,

@@ -239,6 +239,7 @@ def _attn_prefill_full(lp: Params, hn: jax.Array, *, cfg: ModelConfig,
     return attention_out(lp["attn"], o), state
 
 
+@jax.named_scope("attn_prefill")
 def _attn_prefill_chunk(lp: Params, state: Dict[str, jax.Array],
                         hn: jax.Array, *, cfg: ModelConfig,
                         seg: SegmentSpec, positions: jax.Array,
@@ -341,6 +342,7 @@ def _attn_prefill_chunk(lp: Params, state: Dict[str, jax.Array],
     return attention_out(lp["attn"], o), new_state
 
 
+@jax.named_scope("attn_decode")
 def _attn_decode_step(lp: Params, state: Dict[str, jax.Array],
                       hn: jax.Array, *, cfg: ModelConfig, seg: SegmentSpec,
                       pos: jax.Array, a3: A3Config, use_kernel: bool,

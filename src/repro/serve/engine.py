@@ -131,11 +131,23 @@ runs the admission state machine::
   sample ids/handoff mask) ride ONE packed int32 ``[slots, CTRL_COLS]``
   array per tick; both the prefill and decode jits slice their columns
   in-graph, so a tick issues a single small upload plus the token
-  block instead of ~9 scattered transfers. Per-phase wall time lands
-  in ``stats["tick_ns_prefill"] / tick_ns_decode / tick_ns_harvest /
-  tick_ns_host``, and ``stats["host_sync_stalls"]`` counts harvests
-  that actually blocked on an unfinished device computation
-  (``is_ready()`` false at drain time).
+  block instead of ~9 scattered transfers.
+  ``stats["host_sync_stalls"]`` counts harvests that actually blocked
+  on an unfinished device computation (``is_ready()`` false at drain
+  time).
+* **Tick phases on the profiler's clock.** Every tick is one
+  ``serve.tick`` span (a ``StepTraceAnnotation`` numbered by the tick,
+  with its prefill and decode lane counts) holding the phases
+  ``serve.admit``, ``serve.plan``, ``serve.dispatch.prefill``,
+  ``serve.prefill.book``, ``serve.dispatch.decode``,
+  ``serve.harvest.wait``, ``serve.harvest.apply`` and ``serve.finish``
+  (:class:`repro.serve.telemetry.phase`). Under ``jax.profiler`` they
+  share the timebase of the device's programs (``jit_prefill_chunk``,
+  ``jit_decode_block``, ...), so device idle time can be charged to
+  the host phase that held it; with telemetry on they also land in
+  the trace ring. ``stats["prefill_positions"]`` counts the padded
+  ``[slots, chunk]`` positions each prefill dispatch computes, beside
+  the real ``prefill_tokens``.
 * **Cache donation.** Both the prefill-chunk and decode-block jits
   donate the cache argument, so ring buffers and recurrent states
   update in place instead of being copied each tick.
@@ -237,9 +249,11 @@ are the queue lane or the slot index the request occupies::
                                    deferred-harvest stall is a visible
                                    gap; TPOT histogram at terminal)
                           ▸ terminal {finished|cancelled|expired|failed}
-    track "engine": host_sync_stall / checkpoint / restore /
-                    chaos_{delay,corrupt,spill,abort,gather_fail} /
-                    prefix-cache + L2 events (hit/evict/demote/promote)
+    track "engine": the tick phases (serve.tick and the serve.* spans
+                    nested in it) / host_sync_stall / checkpoint /
+                    restore / chaos_{delay,corrupt,spill,abort,
+                    gather_fail} / prefix-cache + L2 events
+                    (hit/evict/demote/promote)
 
 Metrics land in the registry (``serve_ttft_ns{terminal=...}``,
 ``serve_tpot_ns``, ``serve_queue_sojourn_ns{...}``, A^3
@@ -282,7 +296,7 @@ from repro.serve.chaos import ChaosError, ChaosInjector, EngineCrash, \
 from repro.serve.page_store import CheckpointError, IntegrityError, \
     deserialize_tree, serialize_tree
 from repro.serve.prefix_cache import PrefixCache
-from repro.serve.telemetry import Telemetry
+from repro.serve.telemetry import Telemetry, phase
 
 
 def make_serve_step(
@@ -291,14 +305,15 @@ def make_serve_step(
     *,
     use_kernel: bool = False,
 ) -> Callable:
-    """Returns step(params, cache, token [B], pos scalar or [B]) ->
-    (logits [B, Vp], new_cache)."""
+    """Returns decode_step(params, cache, token [B], pos scalar or [B])
+    -> (logits [B, Vp], new_cache); jitted, it lowers to the module
+    ``jit_decode_step``."""
 
-    def step(params, cache, token, pos):
+    def decode_step(params, cache, token, pos):
         return decoder.decode_step(params, cfg, cache, token, pos, a3=a3,
                                    use_kernel=use_kernel)
 
-    return step
+    return decode_step
 
 
 # Packed control-word layout: the per-tick scatter of small host int
@@ -330,7 +345,7 @@ def make_decode_block_step(
     temperature: float = 0.0,
     probe: bool = False,
 ) -> Callable:
-    """Returns the blocked-decode dispatch: step(params, cache,
+    """Returns the blocked-decode dispatch: decode_block(params, cache,
     token [B], first_tok [B], ctrl [B, CTRL_COLS][, rng]) ->
     (harvest [B, 1+steps], carry [B], new_cache). ``steps`` decode
     iterations run device-resident under one ``lax.scan`` — in-graph
@@ -358,7 +373,11 @@ def make_decode_block_step(
     captured-score-mass-ratio sum) per lane over the block's advanced
     steps — harvested alongside the ring at the same deferred read, so
     sampling it adds zero host syncs. The token path runs identical
-    ops (see :func:`repro.models.decoder.decode_block`)."""
+    ops (see :func:`repro.models.decoder.decode_block`).
+
+    The returned function is named ``decode_block`` (``probe=True``:
+    ``decode_block_probe``), so its jitted module is
+    ``jit_decode_block`` / ``jit_decode_block_probe`` in a profile."""
 
     def _run(params, cache, token, first_tok, ctrl, rng=None):
         token = jnp.where(ctrl[:, CTRL_D_HMASK] > 0, first_tok, token)
@@ -383,14 +402,15 @@ def make_decode_block_step(
         def step(params, cache, token, first_tok, ctrl):
             return _run(params, cache, token, first_tok, ctrl)
 
-    return step
+    return _named(step, "decode_block_probe" if probe else "decode_block")
 
 
 def make_prefill_chunk_step(cfg: ModelConfig, *, a3: bool = False,
                             update_sort: bool = True,
                             temperature: float = 0.0) -> Callable:
-    """Returns step(params, cache, tokens [B, C], ctrl [B, CTRL_COLS]
-    [, rng]) -> (first_tok [B], new_cache) — the ragged chunked-prefill
+    """Returns prefill_chunk(params, cache, tokens [B, C],
+    ctrl [B, CTRL_COLS][, rng]) -> (first_tok [B], new_cache) — the
+    ragged chunked-prefill
     dispatch with the device-resident prefill->decode handoff: each
     lane's next-token draw from its last valid position's logits
     happens in-graph, so finishing lanes hand their first generated
@@ -404,7 +424,9 @@ def make_prefill_chunk_step(cfg: ModelConfig, *, a3: bool = False,
     specialization that treats the sorted-key leaves as read-only
     (dispatched on ticks where no lane finishes its prompt). The
     ``rng`` argument exists only when ``temperature > 0`` (greedy
-    dispatches keep the production signature)."""
+    dispatches keep the production signature). Its jitted module is
+    ``jit_prefill_chunk`` (``update_sort=False``:
+    ``jit_prefill_chunk_nosort``)."""
 
     def _mark_poison(tok, logits):
         # poison quarantine rides the handoff: a finishing lane whose
@@ -436,7 +458,16 @@ def make_prefill_chunk_step(cfg: ModelConfig, *, a3: bool = False,
             return _mark_poison(decoder.sample_logits(logits),
                                 logits), cache
 
-    return step
+    return _named(step, "prefill_chunk" if update_sort
+                  else "prefill_chunk_nosort")
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """Name a step program: ``jax.jit`` names its module ``jit_<name>``
+    after the function, which is how a profile tells the programs
+    apart."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class Request(NamedTuple):
@@ -529,7 +560,7 @@ class _PendingHarvest:
     # telemetry: the A^3 quality-probe array ([slots, 3], present only
     # on sampled dispatches — it rides the same drain as ``full``, so
     # reading it adds no host sync event) and the dispatch timestamp
-    # (monotonic ns) anchoring the block's trace span
+    # (the telemetry ``Tracer`` clock, ns) anchoring the block's span
     probe: Any = None
     t_dispatch: int = 0
 
@@ -775,13 +806,12 @@ class ServeEngine:
                       "l2_spills": 0, "l2_hits": 0, "l2_evictions": 0,
                       "l2_integrity_drops": 0, "checkpoints": 0,
                       "restores": 0,
-                      # per-phase tick timing (monotonic-clock ns;
-                      # chaos delays are virtual so they add no wall
-                      # time) + harvest reads that actually blocked on
-                      # an unfinished device block
-                      "tick_ns_prefill": 0, "tick_ns_decode": 0,
-                      "tick_ns_harvest": 0, "tick_ns_host": 0,
-                      "host_sync_stalls": 0}
+                      # harvest reads that actually blocked on an
+                      # unfinished device block
+                      "host_sync_stalls": 0,
+                      # token positions the padded prefill dispatches
+                      # computed (slots x chunk each), real or not
+                      "prefill_positions": 0}
         if self._tm is not None:
             # compatibility view: the legacy stats dict is exported by
             # the registry at exposition time (read by reference — the
@@ -990,61 +1020,52 @@ class ServeEngine:
         caller counts the abort; ``run_to_completion`` does)."""
         self.stats["ticks"] += 1
         tick = self.stats["ticks"]
-        t0 = time.monotonic_ns()
-        h0 = self.stats["tick_ns_harvest"]
-        p_ns = d_ns = 0
-        ch = self._chaos
-        if ch is not None:
-            ch.phase(tick, "tick_start")
-            if ch.consume_delay():
-                # virtual stall: the whole tick does no work (the
-                # wall-clock-free replacement for the old time.sleep
-                # delay — deterministic, and deadlines still elapse)
-                self.stats["chaos_delayed_ticks"] += 1
-                if self._tm is not None:
-                    self._tm.event("chaos_delay", tick=tick)
-                self.stats["tick_ns_host"] += time.monotonic_ns() - t0
-                return
-            spill = ch.pick_spill(tick)
-            if spill and self._pc is not None:
-                if self._tm is not None:
-                    self._tm.event("chaos_spill", tick=tick, pages=spill)
-                self._pc.spill(spill)
-        self._expire_tick()
-        self._admit()
-        if ch is not None:
-            ch.phase(tick, "pre_prefill")
-        if any(s.phase == PREFILLING for s in self.slots):
-            # an aborted tick (injected mid-tick raise) can leave
-            # handoff first tokens unharvested; resolve them with a
-            # direct read BEFORE the prefill dispatch overwrites
-            # ``_first_tok`` (and before planning reads slot state)
-            self._flush_stale_handoff()
-        # plan both dispatch phases, pack their control words into ONE
-        # transfer (the decode plan simulates the prefill plan's slot
-        # transitions, so it needs no sync in between)
-        ctrl = np.zeros((len(self.slots), CTRL_COLS), np.int32)
-        ctrl[:, CTRL_D_POS] = -1
-        plan_p = self._plan_prefill(ctrl)
-        plan_d = self._plan_decode(plan_p, ctrl)
-        ctrl_dev = (jnp.asarray(ctrl)
-                    if plan_p is not None or plan_d is not None else None)
-        tp = time.monotonic_ns()
-        self._prefill_tick(plan_p, ctrl_dev)
-        p_ns = time.monotonic_ns() - tp
-        if ch is not None:
-            ch.phase(tick, "pre_advance")
-        self._corrupt_tick()
-        hd = self.stats["tick_ns_harvest"]
-        td = time.monotonic_ns()
-        self._advance(plan_d, ctrl_dev)
-        d_ns = max(0, time.monotonic_ns() - td
-                   - (self.stats["tick_ns_harvest"] - hd))
-        self.stats["tick_ns_prefill"] += p_ns
-        self.stats["tick_ns_decode"] += d_ns
-        self.stats["tick_ns_host"] += max(
-            0, time.monotonic_ns() - t0 - p_ns - d_ns
-            - (self.stats["tick_ns_harvest"] - h0))
+        tm = self._tm
+        with phase("serve.tick", tm, step_num=tick) as span:
+            ch = self._chaos
+            if ch is not None:
+                ch.phase(tick, "tick_start")
+                if ch.consume_delay():
+                    # virtual stall: the whole tick does no work (the
+                    # wall-clock-free replacement for the old time.sleep
+                    # delay — deterministic, and deadlines still elapse)
+                    self.stats["chaos_delayed_ticks"] += 1
+                    if tm is not None:
+                        tm.event("chaos_delay", tick=tick)
+                    return
+                spill = ch.pick_spill(tick)
+                if spill and self._pc is not None:
+                    if tm is not None:
+                        tm.event("chaos_spill", tick=tick, pages=spill)
+                    self._pc.spill(spill)
+            with phase("serve.admit", tm):
+                self._expire_tick()
+                self._admit()
+            if ch is not None:
+                ch.phase(tick, "pre_prefill")
+            if any(s.phase == PREFILLING for s in self.slots):
+                # an aborted tick (injected mid-tick raise) can leave
+                # handoff first tokens unharvested; resolve them with a
+                # direct read BEFORE the prefill dispatch overwrites
+                # ``_first_tok`` (and before planning reads slot state)
+                self._flush_stale_handoff()
+            # plan both dispatch phases, pack their control words into
+            # ONE transfer (the decode plan simulates the prefill plan's
+            # slot transitions, so it needs no sync in between)
+            with phase("serve.plan", tm):
+                ctrl = np.zeros((len(self.slots), CTRL_COLS), np.int32)
+                ctrl[:, CTRL_D_POS] = -1
+                plan_p = self._plan_prefill(ctrl)
+                plan_d = self._plan_decode(plan_p, ctrl)
+                ctrl_dev = (jnp.asarray(ctrl) if plan_p is not None
+                            or plan_d is not None else None)
+            span.set(prefill_lanes=len(plan_p["pre"]) if plan_p else 0,
+                     decode_lanes=len(plan_d["active"]) if plan_d else 0)
+            self._prefill_tick(plan_p, ctrl_dev)
+            if ch is not None:
+                ch.phase(tick, "pre_advance")
+            self._corrupt_tick()
+            self._advance(plan_d, ctrl_dev)
 
     def run_to_completion(self, max_ticks: int = 10_000):
         """Tick until no work remains. Injected tick aborts
@@ -1250,8 +1271,10 @@ class ServeEngine:
                 f"checkpoint A^3 mode {state['a3_mode']!r} does not "
                 f"match {a3.mode.value!r}")
         eng = cls(params, cfg, a3=a3, chaos=chaos, **state["engine"])
-        # stats is SHARED with the prefix cache: update in place
-        eng.stats.update({k: int(v) for k, v in state["stats"].items()})
+        # stats is SHARED with the prefix cache: update in place; keys
+        # this engine no longer keeps (an older checkpoint's) drop
+        eng.stats.update({k: int(v) for k, v in state["stats"].items()
+                          if k in eng.stats})
         eng._uid = int(state["uid"])
         eng._draining = bool(state["draining"])
         eng._status = {int(k): v for k, v in state["status"].items()}
@@ -1501,7 +1524,7 @@ class ServeEngine:
             # the final chunk; meaningless and unused for other lanes)
             ctrl[si, CTRL_P_SPOS] = s.cursor + take - 1
             ctrl[si, CTRL_P_SIDS] = s.uid
-        return {"pre": pre, "takes": takes, "tokens": tokens,
+        return {"pre": pre, "takes": takes, "tokens": jnp.asarray(tokens),
                 "sort_any": sort_any}
 
     def _prefill_tick(self, plan: Optional[Dict[str, Any]],
@@ -1513,26 +1536,37 @@ class ServeEngine:
         if plan is None:
             return
         pre, takes = plan["pre"], plan["takes"]
-        ps = self.page_size
         fn = self._prefill
         if self._prefill_nosort is not None and not plan["sort_any"]:
             fn = self._prefill_nosort
-        args = (self.params, self.cache, jnp.asarray(plan["tokens"]),
-                ctrl_dev)
-        t_disp = time.monotonic_ns() if self._tm is not None else 0
-        if self._sample_rng is not None:
-            first_tok, self.cache = fn(*args, self._sample_rng)
-        else:
-            first_tok, self.cache = fn(*args)
+        tokens = plan["tokens"]
+        args = (self.params, self.cache, tokens, ctrl_dev)
+        tm = self._tm
+        with phase("serve.dispatch.prefill", tm, lanes=len(pre),
+                   tokens=sum(takes.values()),
+                   positions=tokens.size) as disp:
+            if self._sample_rng is not None:
+                first_tok, self.cache = fn(*args, self._sample_rng)
+            else:
+                first_tok, self.cache = fn(*args)
         self.stats["prefill_dispatches"] += 1
+        self.stats["prefill_positions"] += tokens.size
+        with phase("serve.prefill.book", tm):
+            self._book_prefill(plan, first_tok, disp)
+
+    def _book_prefill(self, plan: Dict[str, Any], first_tok,
+                      disp: phase) -> None:
+        """Host bookkeeping after a prefill dispatch: per-lane cursors,
+        prefix-cache page records, and the prefill -> decode handoff."""
+        pre, takes = plan["pre"], plan["takes"]
+        ps = self.page_size
         if self._tm is not None:
             # one ragged dispatch serves every prefilling lane; each
             # lane gets a span of the shared dispatch wall time
-            dur = time.monotonic_ns() - t_disp
             for si in pre:
                 s = self.slots[si]
-                self._tm.on_prefill_chunk(s.uid, si, ts_ns=t_disp,
-                                          dur_ns=dur, pos=s.cursor,
+                self._tm.on_prefill_chunk(s.uid, si, ts_ns=disp.t0_ns,
+                                          dur_ns=disp.dur_ns, pos=s.cursor,
                                           chunk=takes[si])
         for si in pre:
             s = self.slots[si]
@@ -1588,16 +1622,25 @@ class ServeEngine:
         if not self._handoff:
             return
         self._drain_harvests()
-        th = time.monotonic_ns()
-        first = np.asarray(self._first_tok)
+        self._read_handoff(self._handoff)
+        self._handoff = set()
+        self._first_tok = None
+        self._finish_done_slots()
+
+    def _read_handoff(self, handoff: set) -> None:
+        """Land the first tokens of ``handoff`` lanes with one direct
+        read of the prefill dispatch's device-resident output."""
+        with phase("serve.harvest.wait", self._tm, forced=1):
+            first = np.asarray(self._first_tok)
         self.stats["host_syncs"] += 1
         self.stats["handoff_syncs"] += 1
-        for si in sorted(self._handoff):
+        for si in sorted(handoff):
             s = self.slots[si]
             if not s.decoding:
                 continue               # released while the token was stale
             tok = int(first[si])
             if tok == decoder.POISON:
+                # non-finite prompt logits: quarantine
                 self._release_slot(si, FAILED)
             else:
                 s.generated.append(tok)
@@ -1607,10 +1650,6 @@ class ServeEngine:
             # device carry has no valid entry for it: the next block
             # rebuilds its input from ``generated`` (cold path)
             self._carry_ok[si] = False
-        self._handoff = set()
-        self._first_tok = None
-        self.stats["tick_ns_harvest"] += time.monotonic_ns() - th
-        self._finish_done_slots()
 
     def _plan_decode(self, plan_p: Optional[Dict[str, Any]],
                      ctrl: np.ndarray) -> Optional[Dict[str, Any]]:
@@ -1666,24 +1705,7 @@ class ServeEngine:
             # budget == 1 or a max_len-length prompt)
             self._drain_harvests()
             if handoff:
-                th = time.monotonic_ns()
-                first = np.asarray(self._first_tok)
-                self.stats["host_syncs"] += 1
-                self.stats["handoff_syncs"] += 1
-                for si in sorted(handoff):
-                    s = self.slots[si]
-                    if not s.decoding:
-                        continue
-                    tok = int(first[si])
-                    if tok == decoder.POISON:
-                        # non-finite prompt logits: quarantine
-                        self._release_slot(si, FAILED)
-                    else:
-                        s.generated.append(tok)
-                        if self._tm is not None:
-                            self._tm.on_first_token(s.uid)
-                    self._carry_ok[si] = False
-                self.stats["tick_ns_harvest"] += time.monotonic_ns() - th
+                self._read_handoff(handoff)
             self._finish_done_slots()
             return
         # blocked ragged decode: every advanceable slot moves up to
@@ -1735,11 +1757,12 @@ class ServeEngine:
         if self._decode_block_probe is not None and \
                 self.stats["decode_dispatches"] % self.telemetry_every == 0:
             fn = self._decode_block_probe
-        t_disp = time.monotonic_ns() if self._tm is not None else 0
-        if self._sample_rng is not None:
-            out = fn(*args, self._sample_rng)
-        else:
-            out = fn(*args)
+        with phase("serve.dispatch.decode", self._tm, lanes=len(active),
+                   steps=t) as disp:
+            if self._sample_rng is not None:
+                out = fn(*args, self._sample_rng)
+            else:
+                out = fn(*args)
         if fn is self._decode_block:
             full, carry, self.cache = out
         else:
@@ -1780,7 +1803,7 @@ class ServeEngine:
             refs={},
             ready_at=(time.monotonic() + self.virtual_device_latency_s
                       if self.virtual_device_latency_s > 0.0 else 0.0),
-            probe=probe_out, t_dispatch=t_disp)
+            probe=probe_out, t_dispatch=disp.t0_ns)
         for si, uid in entry.handoff:
             entry.refs[si] = uid
         for si, uid, nb, _pos0 in entry.lanes:
@@ -1810,10 +1833,10 @@ class ServeEngine:
         pipeline's pre-dispatch drain mostly finds the data ready)."""
         if len(self._pending) <= keep:
             return
-        th = time.monotonic_ns()
         now = time.monotonic()
         entries = [self._pending.popleft()
                    for _ in range(len(self._pending) - keep)]
+        forced = len(entries)
         if any(not _block_done(e.full) or e.ready_at > now
                for e in entries):
             self.stats["host_sync_stalls"] += 1
@@ -1830,16 +1853,20 @@ class ServeEngine:
                 and self._pending[0].ready_at <= now:
             entries.append(self._pending.popleft())
         self.stats["host_syncs"] += 1
-        for e in entries:
-            # virtual-device emulation: a block is unreadable before
-            # its emulated completion; the sleep releases the GIL, so
-            # real XLA compute (and nothing else, on the synchronous
-            # path) proceeds underneath it
-            wait = e.ready_at - time.monotonic()
-            if wait > 0.0:
-                time.sleep(wait)
-            self._apply_harvest(e, np.asarray(e.full))
-        self.stats["tick_ns_harvest"] += time.monotonic_ns() - th
+        rows = []
+        with phase("serve.harvest.wait", self._tm, forced=forced):
+            for e in entries:
+                # virtual-device emulation: a block is unreadable before
+                # its emulated completion; the sleep releases the GIL,
+                # so real XLA compute (and nothing else, on the
+                # synchronous path) proceeds underneath it
+                wait = e.ready_at - time.monotonic()
+                if wait > 0.0:
+                    time.sleep(wait)
+                rows.append(np.asarray(e.full))
+        with phase("serve.harvest.apply", self._tm):
+            for e, h in zip(entries, rows):
+                self._apply_harvest(e, h)
 
     def _apply_harvest(self, e: _PendingHarvest, h: np.ndarray):
         """Run one block's deferred host bookkeeping against its
@@ -1850,7 +1877,7 @@ class ServeEngine:
         was in flight contributes nothing to its slot's successor."""
         tm = self._tm
         if tm is not None:
-            now = time.monotonic_ns()
+            now = tm.tracer.now_ns()
             tm.on_decode_block(
                 [(si, uid) for si, uid, _nb, _p0 in e.lanes],
                 ts_ns=e.t_dispatch or now,
@@ -1908,10 +1935,11 @@ class ServeEngine:
                 s.pending = max(0, s.pending - 1)
 
     def _finish_done_slots(self):
-        for si, s in enumerate(self.slots):
-            if s.decoding and s.pending == 0 \
-                    and (s.budget <= 0 or s.pos >= self.max_len - 1):
-                self._finish(si)
+        with phase("serve.finish", self._tm):
+            for si, s in enumerate(self.slots):
+                if s.decoding and s.pending == 0 \
+                        and (s.budget <= 0 or s.pos >= self.max_len - 1):
+                    self._finish(si)
 
     def _finish(self, si: int):
         slot = self.slots[si]

@@ -1,7 +1,8 @@
 """Serving telemetry plane: metrics registry, per-request tracing,
 Chrome-trace export, and A^3 approximation-quality probe aggregation.
 
-Three pillars, all host-side and allocation-free on the hot path:
+Four parts, all host-side (the first three allocation-free on the hot
+path):
 
 * ``MetricsRegistry`` — named counters, gauges, and fixed-bucket
   histograms.  Histograms use log-spaced nanosecond buckets whose
@@ -23,9 +24,15 @@ Three pillars, all host-side and allocation-free on the hot path:
   that were computed in-graph and harvested on the already-landing
   ring read; this module only accumulates and exposes them.
 
-Everything here is plain Python + numpy: no jax imports, so the
-module is importable from analysis tooling without pulling in a
-device runtime.
+* ``phase`` — the engine's tick-phase spans (``serve.tick`` and the
+  ``serve.*`` phases nested in it) on the profiler's clock: each opens
+  a ``jax.profiler.TraceAnnotation``, so a profiler trace holds the
+  host phases on the same timebase as the device's programs, and with
+  telemetry on the same span also lands in the ``Tracer`` ring.
+
+Everything here is plain Python + numpy: jax is imported lazily, the
+first time a ``phase`` opens, so the module is importable from analysis
+tooling without pulling in a device runtime.
 """
 from __future__ import annotations
 
@@ -36,6 +43,8 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+
+_PROFILER: Any = None      # jax.profiler, imported by the first phase
 
 # Log-spaced latency buckets: powers of two from 1us to ~1100s.  30
 # buckets + overflow covers everything from a sub-tick host op to a
@@ -524,3 +533,61 @@ class Telemetry:
     def write_metrics(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.metrics_snapshot(), f, indent=2, sort_keys=True)
+
+
+# ---------------------------------------------------------------------------
+# Tick phases on the profiler's clock
+
+
+class phase:
+    """Context manager for one engine tick phase: ``with phase(name, tm,
+    **args):``.
+
+    Opens a ``jax.profiler.TraceAnnotation`` named ``name`` (a
+    ``StepTraceAnnotation`` when ``args`` holds ``step_num``) while the
+    profiler is tracing, and when ``tm`` (the engine's
+    :class:`Telemetry`) is given, records the same span on the
+    ``"engine"`` track of its ``Tracer`` ring. With neither, entering
+    and leaving cost one ``is_enabled`` check. ``args`` travel with the
+    span; :meth:`set` adds ones known only inside it. ``t0_ns`` and
+    ``dur_ns`` hold the span's ``Tracer`` clock readings when ``tm`` is
+    given (0 otherwise), for telemetry spans that share its timing.
+    """
+
+    __slots__ = ("name", "tm", "args", "t0_ns", "dur_ns", "_ann")
+
+    def __init__(self, name: str, tm: Optional["Telemetry"] = None,
+                 **args: Any) -> None:
+        self.name, self.tm, self.args = name, tm, args
+        self.t0_ns = self.dur_ns = 0
+        self._ann = None
+
+    def __enter__(self) -> "phase":
+        global _PROFILER
+        if _PROFILER is None:
+            from jax import profiler
+            _PROFILER = profiler
+        if _PROFILER.TraceAnnotation.is_enabled():
+            cls = (_PROFILER.StepTraceAnnotation if "step_num" in self.args
+                   else _PROFILER.TraceAnnotation)
+            self._ann = cls(self.name, **self.args)
+            self._ann.__enter__()
+        if self.tm is not None:
+            self.t0_ns = self.tm.tracer.now_ns()
+        return self
+
+    def set(self, **args: Any) -> None:
+        """Add ``args`` to the open span."""
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+        if self.tm is not None:
+            self.args.update(args)
+
+    def __exit__(self, *exc: Any) -> None:
+        if self.tm is not None:
+            self.dur_ns = self.tm.tracer.now_ns() - self.t0_ns
+            self.tm.tracer.span(self.name, ts_ns=self.t0_ns,
+                                dur_ns=self.dur_ns,
+                                args=self.args or None)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)

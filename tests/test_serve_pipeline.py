@@ -26,9 +26,8 @@ accounting at harvest). None of that may change WHAT is generated:
 * crash/restore with a deferred harvest in flight resumes
   token-for-token (checkpoints drain pending harvests first),
 * and the perf counters move the right way: strictly fewer blocking
-  ``host_syncs`` at depth 1 on a decode-heavy workload, sane
-  ``tick_ns_*`` phase timings, and the carry-returning decode block
-  lowering on the 8-device CI mesh.
+  ``host_syncs`` at depth 1 on a decode-heavy workload, and the
+  carry-returning decode block lowering on the 8-device CI mesh.
 """
 from __future__ import annotations
 
@@ -174,12 +173,11 @@ def test_pipeline_depth_zero_pins_default_engine(all_params):
     got, e0, u0 = _run(params, TINY, prompts, depth=0)
     assert [e0.result(u0[i]) for i in range(len(prompts))] == \
         [eng_default.result(u) for u in uids]
-    # tick_ns_* are wall-clock; host_sync_stalls depends on whether the
-    # device finished before the drain checked is_ready() — a race
-    # against real time, not part of the deterministic contract
+    # host_sync_stalls depends on whether the device finished before
+    # the drain checked is_ready() — a race against real time, not part
+    # of the deterministic contract
     strip = lambda st: {k: v for k, v in st.items()
-                        if not k.startswith("tick_ns")
-                        and k != "host_sync_stalls"}
+                        if k != "host_sync_stalls"}
     assert strip(e0.stats) == strip(eng_default.stats)
 
 
@@ -368,31 +366,6 @@ def test_pipeline_host_syncs_strictly_lower(all_params):
             block, e1.stats["host_syncs"], e0.stats["host_syncs"])
         # stalls only count harvests that actually blocked
         assert 0 <= e1.stats["host_sync_stalls"] <= e1.stats["host_syncs"]
-
-
-def test_pipeline_timing_stats_sane(all_params):
-    """tick_ns_* phase timings: non-negative, present at every depth,
-    and their sum never exceeds the wall time of the run."""
-    params = all_params["tiny"]
-    prompts = _prompts(TINY.vocab_size)
-    for depth in (0, 1):
-        eng = ServeEngine(params, TINY, slots=2, max_len=MAX_LEN,
-                          prefill_chunk=8, decode_block=2,
-                          pipeline_depth=depth)
-        uids = [eng.submit(p, max_new_tokens=MAX_NEW) for p in prompts]
-        t0 = time.monotonic_ns()
-        eng.run_to_completion()
-        wall = time.monotonic_ns() - t0
-        keys = ["tick_ns_prefill", "tick_ns_decode", "tick_ns_harvest",
-                "tick_ns_host"]
-        for k in keys:
-            assert eng.stats[k] >= 0, (depth, k)
-        assert sum(eng.stats[k] for k in keys) <= wall, depth
-        # the engine did real per-phase work: decode + host are nonzero
-        assert eng.stats["tick_ns_decode"] > 0
-        assert eng.stats["tick_ns_host"] > 0
-        for u in uids:
-            assert eng.status(u) == "finished"
 
 
 # ---------------------------------------------------------------------------
