@@ -715,12 +715,19 @@ def prefill_chunk(
     a3: bool = False,
     sort_lanes: Optional[jax.Array] = None,   # [B] bool; default: length > 0
     update_sort: bool = True,                 # static: False = sk leaves RO
+    lane_slot: Optional[jax.Array] = None,    # [B] int32 distinct slots
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Extend per-slot decode caches with one ragged batch of prompt chunks.
 
     Every lane processes ``length[b]`` tokens of its prompt starting at
     absolute position ``pos[b]`` — a single dispatch serves slots at
-    arbitrary prompt cursors (ragged admission prefill). Works for every
+    arbitrary prompt cursors (ragged admission prefill). Without
+    ``lane_slot`` lane ``b`` is cache slot ``b`` (``B`` = slots). With
+    it, the ``B`` lanes are a packed subset of the slots: lane ``b``
+    reads and writes slot ``lane_slot[b]`` (distinct slots), each
+    segment gathers those slots' state on the slot axis, runs the same
+    chunk maths on ``B`` lanes, and scatters the new state back into
+    the cache; the other slots' rows are not touched. Works for every
     segment kind through the mixer-state interface: attention segments
     extend their KV rings, recurrent segments (RG-LRU conv tail + LRU
     hidden, mLSTM matrix memory, sLSTM cell state) carry their
@@ -753,6 +760,8 @@ def prefill_chunk(
 
     Returns (logits [B, Vp] at each lane's last valid position, cache).
     """
+    if lane_slot is not None:
+        lane_slot = jnp.asarray(lane_slot, jnp.int32)
     b, c = tokens.shape
     h = embed_tokens(params, cfg, tokens)
     pos = jnp.asarray(pos, jnp.int32)
@@ -784,7 +793,13 @@ def prefill_chunk(
             hh, _ = _ffn_block(lp, hh, cfg, seg)
             return hh, ns
 
-        h, new_seg = jax.lax.scan(body, h, (params[f"seg{si}"], mut))
+        lanes = mut if lane_slot is None else \
+            jax.tree.map(lambda x: x[:, lane_slot], mut)
+        h, new_seg = jax.lax.scan(body, h, (params[f"seg{si}"], lanes))
+        if lane_slot is not None:
+            new_seg = jax.tree.map(
+                lambda x, y: x.at[:, lane_slot].set(y, unique_indices=True),
+                mut, new_seg)
         new_cache[f"seg{si}"] = {**new_seg, **ro}
     bidx = jnp.arange(b, dtype=jnp.int32)
     last = jnp.clip(length - 1, 0, c - 1)

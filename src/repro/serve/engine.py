@@ -36,10 +36,18 @@ runs the admission state machine::
   recording sibling pages — pool pages are never mutated.
 * **Chunked ragged prefill — one dispatch per tick, every arch.** All
   PREFILLING slots advance by at most ``prefill_chunk`` prompt tokens
-  in a *single* jitted ``prefill_chunk`` dispatch: a padded
-  ``[slots, chunk]`` token block with per-slot start positions and
-  lengths (lanes not prefilling ride along with length 0 and their
-  cache rows pass through untouched). The per-segment mixer-state
+  in a *single* jitted ``prefill_chunk`` dispatch that computes only
+  the prefilling lanes: a packed ``[b, chunk]`` token block, ``b`` the
+  next power of two of the prefilling lanes (at most ``slots``), with a
+  ``[b]`` ``lane_slot`` vector naming each lane's slot and per-lane
+  start positions and lengths. The program gathers those slots' cache
+  state, computes ``b`` lanes, and scatters the new state back; padding
+  lanes take other slots at length 0 and pass their rows through
+  untouched. At ``b == slots`` the lanes are the slots in order and the
+  program is the unpacked ``[slots, chunk]`` one. Every width is
+  compiled at the first prefill tick of each chunk length (one no-op
+  dispatch each), so changing widths never compiles mid-stream. The
+  per-segment mixer-state
   interface (``repro.models.mixer``) carries mid-prompt state for
   recurrent segments across chunk boundaries, so hybrid RG-LRU / xLSTM
   stacks admit through the same bounded-tick path as attention-only
@@ -130,8 +138,10 @@ runs the admission state machine::
   scalars (prefill start/len/sort/sample columns; decode pos/budget/
   sample ids/handoff mask) ride ONE packed int32 ``[slots, CTRL_COLS]``
   array per tick; both the prefill and decode jits slice their columns
-  in-graph, so a tick issues a single small upload plus the token
-  block instead of ~9 scattered transfers.
+  in-graph (a packed prefill gathers its lanes' rows by ``lane_slot``),
+  so a tick issues a single small upload plus the token block (and a
+  packed prefill's ``[b]`` lane vector) instead of ~9 scattered
+  transfers.
   ``stats["host_sync_stalls"]`` counts harvests that actually blocked
   on an unfinished device computation (``is_ready()`` false at drain
   time).
@@ -145,9 +155,10 @@ runs the admission state machine::
   share the timebase of the device's programs (``jit_prefill_chunk``,
   ``jit_decode_block``, ...), so device idle time can be charged to
   the host phase that held it; with telemetry on they also land in
-  the trace ring. ``stats["prefill_positions"]`` counts the padded
-  ``[slots, chunk]`` positions each prefill dispatch computes, beside
-  the real ``prefill_tokens``.
+  the trace ring. ``stats["prefill_lanes_computed"]`` sums the width
+  ``b`` of every prefill dispatch (the ``width`` of its span) and
+  ``stats["prefill_positions"]`` the ``b x chunk`` positions it
+  computes, beside the real ``prefill_tokens``.
 * **Cache donation.** Both the prefill-chunk and decode-block jits
   donate the cache argument, so ring buffers and recurrent states
   update in place instead of being copied each tick.
@@ -409,57 +420,70 @@ def make_prefill_chunk_step(cfg: ModelConfig, *, a3: bool = False,
                             update_sort: bool = True,
                             temperature: float = 0.0) -> Callable:
     """Returns prefill_chunk(params, cache, tokens [B, C],
-    ctrl [B, CTRL_COLS][, rng]) -> (first_tok [B], new_cache) — the
-    ragged chunked-prefill
+    ctrl [slots, CTRL_COLS][, rng], lane_slot=None) -> (first_tok
+    [slots], new_cache) — the ragged chunked-prefill
     dispatch with the device-resident prefill->decode handoff: each
     lane's next-token draw from its last valid position's logits
     happens in-graph, so finishing lanes hand their first generated
     token straight to the same tick's decode block without a blocking
     read (non-finishing lanes' entries are meaningless and ignored).
     The per-lane scalars ride the shared packed ``ctrl`` upload
-    (``CTRL_P_*`` columns): chunk start ``pos``, chunk ``length``,
-    ``sort_lanes`` marking lanes on their final chunk (A^3: fold the
-    completed prompt into the column sort), and the handoff draw's
-    sampling position / uid. ``update_sort=False`` builds the cheaper
+    (``CTRL_P_*`` columns, one row per slot): chunk start ``pos``, chunk
+    ``length``, ``sort_lanes`` marking lanes on their final chunk (A^3:
+    fold the completed prompt into the column sort), and the handoff
+    draw's sampling position / uid. Without ``lane_slot`` the token
+    block has one lane per slot (``B`` = slots). With a ``[B]``
+    ``lane_slot`` of distinct slots the block is packed: lane ``b``
+    prefills slot ``lane_slot[b]``, the program gathers those slots'
+    ``ctrl`` rows and cache state, computes ``B`` lanes only, and
+    scatters the new state and the sampled tokens back by slot
+    (:func:`repro.models.decoder.prefill_chunk`). ``update_sort=False``
+    builds the cheaper
     specialization that treats the sorted-key leaves as read-only
     (dispatched on ticks where no lane finishes its prompt). The
     ``rng`` argument exists only when ``temperature > 0`` (greedy
     dispatches keep the production signature). Its jitted module is
     ``jit_prefill_chunk`` (``update_sort=False``:
-    ``jit_prefill_chunk_nosort``)."""
+    ``jit_prefill_chunk_nosort``) at every width."""
 
-    def _mark_poison(tok, logits):
+    def _run(params, cache, tokens, ctrl, lane_slot, rng=None):
+        pc = ctrl if lane_slot is None else ctrl[lane_slot]
+        logits, cache = decoder.prefill_chunk(
+            params, cfg, cache, tokens, pc[:, CTRL_P_POS],
+            pc[:, CTRL_P_LEN], a3=a3, sort_lanes=pc[:, CTRL_P_SORT] > 0,
+            update_sort=update_sort, lane_slot=lane_slot)
+        if rng is None:
+            tok = decoder.sample_logits(logits)
+        else:
+            tok = decoder.sample_logits(logits, temperature=temperature,
+                                        rng=rng, pos=pc[:, CTRL_P_SPOS],
+                                        ids=pc[:, CTRL_P_SIDS])
         # poison quarantine rides the handoff: a finishing lane whose
         # prompt logits are non-finite hands POISON to the decode block
         # (or the direct read) instead of a garbage token — healthy
         # lanes take the identical select, bit-for-bit
         finite = jnp.all(jnp.isfinite(logits), axis=-1)
-        return jnp.where(finite, tok, decoder.POISON)
+        tok = jnp.where(finite, tok, decoder.POISON)
+        if lane_slot is not None:
+            tok = jnp.zeros((ctrl.shape[0],), tok.dtype).at[lane_slot].set(
+                tok, unique_indices=True)
+        return tok, cache
 
     if temperature > 0.0:
-        def step(params, cache, tokens, ctrl, rng):
-            logits, cache = decoder.prefill_chunk(
-                params, cfg, cache, tokens, ctrl[:, CTRL_P_POS],
-                ctrl[:, CTRL_P_LEN], a3=a3,
-                sort_lanes=ctrl[:, CTRL_P_SORT] > 0,
-                update_sort=update_sort)
-            tok = decoder.sample_logits(logits, temperature=temperature,
-                                        rng=rng,
-                                        pos=ctrl[:, CTRL_P_SPOS],
-                                        ids=ctrl[:, CTRL_P_SIDS])
-            return _mark_poison(tok, logits), cache
+        def step(params, cache, tokens, ctrl, rng, lane_slot=None):
+            return _run(params, cache, tokens, ctrl, lane_slot, rng)
     else:
-        def step(params, cache, tokens, ctrl):
-            logits, cache = decoder.prefill_chunk(
-                params, cfg, cache, tokens, ctrl[:, CTRL_P_POS],
-                ctrl[:, CTRL_P_LEN], a3=a3,
-                sort_lanes=ctrl[:, CTRL_P_SORT] > 0,
-                update_sort=update_sort)
-            return _mark_poison(decoder.sample_logits(logits),
-                                logits), cache
+        def step(params, cache, tokens, ctrl, lane_slot=None):
+            return _run(params, cache, tokens, ctrl, lane_slot)
 
     return _named(step, "prefill_chunk" if update_sort
                   else "prefill_chunk_nosort")
+
+
+def _prefill_width(lanes: int, slots: int) -> int:
+    """The packed prefill width for ``lanes`` prefilling lanes: the
+    next power of two, at most ``slots``."""
+    return min(1 << (lanes - 1).bit_length(), slots)
 
 
 def _named(fn: Callable, name: str) -> Callable:
@@ -758,6 +782,8 @@ class ServeEngine:
                 make_prefill_chunk_step(cfg, a3=True, update_sort=False,
                                         temperature=self.temperature),
                 donate_argnums=(1,))
+        # chunk lengths whose every packed width is compiled
+        self._prefill_warm: set = set()
         # device-resident prefill->decode handoff: slots that finished
         # their prompt this tick, whose first sampled token lives only
         # in ``_first_tok`` (the prefill dispatch output) until the next
@@ -809,8 +835,10 @@ class ServeEngine:
                       # harvest reads that actually blocked on an
                       # unfinished device block
                       "host_sync_stalls": 0,
-                      # token positions the padded prefill dispatches
-                      # computed (slots x chunk each), real or not
+                      # lanes and token positions the packed prefill
+                      # dispatches computed (width, width x chunk
+                      # each), real or not
+                      "prefill_lanes_computed": 0,
                       "prefill_positions": 0}
         if self._tm is not None:
             # compatibility view: the legacy stats dict is exported by
@@ -1462,8 +1490,10 @@ class ServeEngine:
         """Plan this tick's chunked-prefill dispatch against the
         post-admission slot table WITHOUT touching any state: compute
         each PREFILLING lane's chunk ``take`` (page-boundary clamping
-        included) and write the ``CTRL_P_*`` columns of the shared
-        packed control block. Returns None when no lane prefills. The
+        included), pack the lanes into the ``[b, chunk]`` token block
+        and its ``lane_slot`` vector (None at full width), and write the
+        ``CTRL_P_*`` columns of the shared packed control block (rows
+        stay keyed by slot). Returns None when no lane prefills. The
         decode plan consumes the result to simulate the prefill's
         slot transitions, so both dispatches issue back-to-back off
         one upload with no sync between them."""
@@ -1472,6 +1502,13 @@ class ServeEngine:
         if not pre:
             return None
         n, c = len(self.slots), self._chunk
+        # only the prefilling lanes are computed, packed to a
+        # power-of-two width; padding lanes take other slots at length
+        # 0 (their rows pass through). At full width the lanes are the
+        # slots in order: the unpacked program, with no lane_slot.
+        b = _prefill_width(len(pre), n)
+        lanes = sorted(pre + [si for si in range(n)
+                              if si not in pre][:b - len(pre)])
         # adaptive chunking: decoders active -> shrink the admission
         # stall to the floor; cold queue -> drain at the full chunk
         if self._chunk_min is not None \
@@ -1479,7 +1516,8 @@ class ServeEngine:
             c = self._chunk_min
             self.stats["adaptive_shrink_ticks"] += 1
         ps = self.page_size
-        tokens = np.zeros((n, c), np.int32)
+        tokens = np.zeros((b, c), np.int32)
+        row = {si: j for j, si in enumerate(lanes)}
         sort_any = False
         takes = {}
         for si in pre:
@@ -1510,7 +1548,7 @@ class ServeEngine:
                     aligned = ((s.cursor + take) // ps) * ps
                     if aligned > s.cursor:
                         take = aligned - s.cursor
-            tokens[si, :take] = s.prompt[s.cursor:s.cursor + take]
+            tokens[row[si], :take] = s.prompt[s.cursor:s.cursor + take]
             ctrl[si, CTRL_P_POS] = s.cursor
             ctrl[si, CTRL_P_LEN] = take
             takes[si] = take
@@ -1525,34 +1563,65 @@ class ServeEngine:
             ctrl[si, CTRL_P_SPOS] = s.cursor + take - 1
             ctrl[si, CTRL_P_SIDS] = s.uid
         return {"pre": pre, "takes": takes, "tokens": jnp.asarray(tokens),
+                "lane_slot": (jnp.asarray(np.array(lanes, np.int32))
+                              if b < n else None),
                 "sort_any": sort_any}
 
     def _prefill_tick(self, plan: Optional[Dict[str, Any]],
                       ctrl_dev) -> None:
         """Advance every PREFILLING slot by one prompt chunk in a single
-        ragged padded dispatch (planned by :meth:`_plan_prefill`);
-        finishing lanes' first tokens are sampled in-graph and stay on
-        device for the decode handoff."""
+        ragged dispatch of the packed ``[b, chunk]`` block (planned by
+        :meth:`_plan_prefill`); finishing lanes' first tokens are
+        sampled in-graph and stay on device for the decode handoff."""
         if plan is None:
             return
         pre, takes = plan["pre"], plan["takes"]
+        tokens = plan["tokens"]
+        width, c = tokens.shape
+        if c not in self._prefill_warm:
+            self._warm_prefill(c)
         fn = self._prefill
         if self._prefill_nosort is not None and not plan["sort_any"]:
             fn = self._prefill_nosort
-        tokens = plan["tokens"]
-        args = (self.params, self.cache, tokens, ctrl_dev)
         tm = self._tm
         with phase("serve.dispatch.prefill", tm, lanes=len(pre),
-                   tokens=sum(takes.values()),
+                   width=width, tokens=sum(takes.values()),
                    positions=tokens.size) as disp:
-            if self._sample_rng is not None:
-                first_tok, self.cache = fn(*args, self._sample_rng)
-            else:
-                first_tok, self.cache = fn(*args)
+            first_tok, self.cache = self._dispatch_prefill(
+                fn, tokens, ctrl_dev, plan["lane_slot"])
         self.stats["prefill_dispatches"] += 1
+        self.stats["prefill_lanes_computed"] += width
         self.stats["prefill_positions"] += tokens.size
         with phase("serve.prefill.book", tm):
             self._book_prefill(plan, first_tok, disp)
+
+    def _dispatch_prefill(self, fn, tokens, ctrl, lane_slot):
+        args = (self.params, self.cache, tokens, ctrl)
+        if self._sample_rng is not None:
+            args += (self._sample_rng,)
+        if lane_slot is None:
+            return fn(*args)
+        return fn(*args, lane_slot=lane_slot)
+
+    def _warm_prefill(self, c: int) -> None:
+        """Compile the prefill program of every packed width (and both
+        A^3 sort variants) for chunk length ``c`` before the first real
+        dispatch at that length: each dispatches once with every
+        length 0, which passes the cache through untouched. Ticks that
+        pack 1, 2, 4, ... lanes then never compile mid-stream."""
+        n = len(self.slots)
+        # device arrays, as the real dispatches pass: numpy arguments
+        # fill another dispatch-cache entry, and the first real
+        # dispatch of each width would trace again
+        ctrl = jnp.zeros((n, CTRL_COLS), jnp.int32)
+        fns = [f for f in (self._prefill, self._prefill_nosort)
+               if f is not None]
+        for b in sorted({_prefill_width(k, n) for k in range(1, n + 1)}):
+            lane_slot = jnp.arange(b, dtype=jnp.int32) if b < n else None
+            for fn in fns:
+                _, self.cache = self._dispatch_prefill(
+                    fn, jnp.zeros((b, c), jnp.int32), ctrl, lane_slot)
+        self._prefill_warm.add(c)
 
     def _book_prefill(self, plan: Dict[str, Any], first_tok,
                       disp: phase) -> None:

@@ -6,8 +6,9 @@
 * a CPU ``jax.profiler`` trace of a few ticks holds one ``serve.tick``
   per tick, every ``serve.*`` phase inside its tick, and one dispatch
   span per dispatch the engine counted;
-* ``stats["prefill_positions"]`` counts the padded ``[slots, chunk]``
-  positions of every prefill dispatch, beside the real
+* ``stats["prefill_positions"]`` counts the positions of every packed
+  ``[b, chunk]`` prefill dispatch (``b`` summed in
+  ``stats["prefill_lanes_computed"]``), beside the real
   ``prefill_tokens``;
 * with telemetry on, the trace ring holds the same phases;
 * the profiler is a pure observer: token streams do not change.
@@ -161,6 +162,8 @@ def test_dispatch_spans_match_the_engine_counters(profiled):
     assert sum(a["tokens"] for a in pre) == eng.stats["prefill_tokens"]
     assert sum(a["positions"] for a in pre) == \
         eng.stats["prefill_positions"]
+    assert sum(a["width"] for a in pre) == \
+        eng.stats["prefill_lanes_computed"]
     assert sum(a["steps"] for a in dec_) == eng.stats["decode_steps"]
 
 
@@ -174,16 +177,32 @@ def test_profiler_does_not_change_token_streams(profiled):
 # the padding counter
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("prefill_chunk, width", [(8, 8), (None, MAX_LEN)])
+@pytest.mark.parametrize("prefill_chunk, width, slots, n_prompts", [
+    pytest.param(8, 8, 2, 4, id="8-8"),
+    pytest.param(None, MAX_LEN, 2, 4, id="None-96"),
+    pytest.param(8, 8, 4, 2, id="8-8-two-of-four-slots"),
+])
 def test_prefill_positions_count_the_padded_block(params, prefill_chunk,
-                                                  width):
-    eng = _engine(params, prefill_chunk=prefill_chunk)
-    _serve(eng)
+                                                  width, slots, n_prompts):
+    """Each prefill dispatch computes its prefilling lanes packed to the
+    next power of two (at most ``slots``), ``width`` positions each."""
+    eng = _engine(params, prefill_chunk=prefill_chunk, slots=slots,
+                  telemetry=True, trace_events=1 << 16)
+    _serve(eng, _prompts()[:n_prompts])
     st = eng.stats
-    assert st["prefill_positions"] == \
-        st["prefill_dispatches"] * len(eng.slots) * width
+    disp = [e[6] for e in eng.tm.tracer.events
+            if e[2] == "serve.dispatch.prefill"]
+    assert len(disp) == st["prefill_dispatches"]
+    assert [d["width"] for d in disp] == [
+        min(1 << (d["lanes"] - 1).bit_length(), slots) for d in disp]
+    assert st["prefill_lanes_computed"] == sum(d["width"] for d in disp)
+    assert st["prefill_positions"] == st["prefill_lanes_computed"] * width
     assert 0 < st["prefill_tokens"] <= st["prefill_positions"]
-    assert st["prefill_tokens"] == sum(PROMPT_LENS)
+    assert st["prefill_tokens"] == sum(PROMPT_LENS[:n_prompts])
+    if slots == 4:
+        # two prompts prefill together in two of four lanes, then the
+        # longer one alone in one
+        assert [(d["lanes"], d["width"]) for d in disp] == [(2, 2), (1, 1)]
 
 
 # ---------------------------------------------------------------------------
