@@ -3,8 +3,9 @@ and the comparison with the plain reference that decides ``correct``.
 
 Everything is found by name: the cell in ``BENCHMARK.json``, its
 configuration in ``configs/<config>.json``, its traffic mix in
-``traffic/<mix>.json``, each metric's reader in ``metrics/<metric>.py``
-and the cell's limits in ``limits/<cell>.json``.
+``traffic/<mix>.json``, its model kind (weights, reference, work counts)
+in ``references/<reference>.py``, each metric's reader in
+``metrics/<metric>.py`` and the cell's limits in ``limits/<cell>.json``.
 """
 from __future__ import annotations
 
@@ -16,17 +17,19 @@ import shutil
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 from chipbench import traffic as T
 from chipbench.readout import Req, Run, Tick, percentile
-from chipbench.workcount import Work, peaks_for
+from chipbench.workcount import peaks_for
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 OUT = BENCH / ".out"
+REFERENCES = BENCH / "references"
 MAX_WARM_TICKS = 400
 
 
@@ -43,6 +46,7 @@ class Cell:
     name: str
     chips: int
     config: dict                  # configs/<config>.json
+    kind: ModuleType              # references/<config["reference"]>.py
     mix: dict                     # traffic/<mix>.json
     end_to_end: List[dict]
     per_layer: List[dict]
@@ -64,21 +68,34 @@ def find_cell(bench: dict, name: str) -> Cell:
     limits = (json.loads(limits_file.read_text())
               if limits_file.is_file() else None)
     return Cell(name=name, chips=int(w["chips"]), config=config,
+                kind=load_kind(config["reference"]),
                 mix=T.load_mix(w["traffic"]),
                 end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
                 per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
                 limits=limits)
 
 
-def load_reader(metric: str) -> Callable[[Run], Optional[float]]:
-    path = BENCH / "metrics" / f"{metric}.py"
+def _load(path: Path, what: str, package: str, name: str) -> ModuleType:
     if not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
+        raise FileNotFoundError(f"no {what} {name!r} at {path}")
     spec = importlib.util.spec_from_file_location(
-        f"chipbench.metrics.{metric.replace('.', '_')}", path)
+        f"chipbench.{package}.{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod      # a dataclass looks its module up there
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str) -> Callable[[Run], Optional[float]]:
+    return _load(BENCH / "metrics" / f"{metric}.py", "reader for metric",
+                 "metrics", metric).read
+
+
+def load_kind(reference: str) -> ModuleType:
+    """The model kind a configuration's ``"reference"`` names: its
+    ``program_params``, ``Reference`` and ``Work``."""
+    return _load(REFERENCES / f"{reference}.py", "model kind",
+                 "references", reference)
 
 
 # ---------------------------------------------------------------------------
@@ -342,8 +359,8 @@ def gap_numbers(gaps: List[np.ndarray]) -> Dict[str, float]:
 
 def reference_gaps(cell: Cell, seed: int, picked: List[Req],
                    control: bool = False):
-    from chipbench.reference import Reference, Sequence
-    ref = Reference(cell.config, int(cell.mix["max_len"]), seed)
+    from chipbench.reference import Sequence
+    ref = cell.kind.Reference(cell.config, int(cell.mix["max_len"]), seed)
     seqs = [Sequence(np.asarray(r.prompt, np.int32),
                      np.asarray(r.tokens, np.int32)) for r in picked]
     return ref.gaps(seqs, control=control)
@@ -353,7 +370,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_process: float, program=None, mix_override: Optional[dict] = None,
              check: bool = True, control: bool = False) -> Outcome:
     import jax
-    from chipbench import weights as W
     from chipbench import tracereduce as TR
     mix = dict(cell.mix, **(mix_override or {}))
     program = program or import_program()
@@ -364,7 +380,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     dev = jax.devices()[0]
     peaks = peaks_for(dev.device_kind) if dev.platform == "tpu" else None
 
-    params = W.program_params(cell.config, seed)
+    params = cell.kind.program_params(cell.config, seed)
     jax.block_until_ready(params)
     engine, cfg = build_engine(dataclasses.replace(cell, mix=mix), params, program)
     check_params_tree(params, cfg, decoder)
@@ -409,8 +425,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     window_ticks = [t for t in client.ticks[n_ticks0:] if t.start < t1]
     run = Run(t0=t0, t1=t1, setup_s=t0 - t_process, loop=mix["loop"],
               requests=client.all, ticks=window_ticks,
-              work=Work(cell.config, int(mix["max_len"])), peaks=peaks,
-              trace=reduced)
+              work=cell.kind.Work(cell.config, int(mix["max_len"])),
+              peaks=peaks, trace=reduced)
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in wanted:

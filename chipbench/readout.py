@@ -8,12 +8,11 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import List, Optional, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from chipbench.tracereduce import Reduced
-from chipbench.workcount import Work
 
 
 @dataclasses.dataclass
@@ -51,7 +50,7 @@ class Run:
     loop: str
     requests: List[Req]
     ticks: List[Tick]           # the ticks that started inside the window
-    work: Work
+    work: Any                   # the model kind's Work (references/<kind>.py)
     peaks: dict
     trace: Optional[Reduced] = None
 

@@ -1,8 +1,6 @@
-"""Plain reference of the dense GQA decoder the configurations describe.
-
-Float32 at the highest matmul precision, one request and one layer at a
-time, with the weights drawn again from the seed (``weights.py``), and
-exact causal attention at every position: the full forward pass over
+"""Plain reference of a decoder, shared by every model kind: float32 at
+the highest matmul precision, one request and one layer at a time, with
+the weights drawn again from the seed, and the full forward pass over
 each prompt with its served tokens.
 
 It imports nothing of the program. ``control=True`` computes the same
@@ -46,6 +44,11 @@ def _fp8(x: jax.Array, axis: int) -> jax.Array:
 
 
 class Reference:
+    """A kind (``references/<kind>.py``) subclasses it with its layer:
+    ``layer_weights(layer)`` draws one layer's weights (``layer`` traced),
+    ``layer_forward(layer, w, h, control)`` runs it over one request's
+    hidden states [s_pad, d]."""
+
     def __init__(self, cfg: dict, ring: int, seed: int):
         if cfg.get("a3"):
             raise ValueError("the reference computes exact attention only")
@@ -53,15 +56,12 @@ class Reference:
         self.key = W.base_key(seed)
         # room for a GAP_ROWS slice that starts at the ring's last row
         self.s_pad = -(-(ring + GAP_ROWS) // Q_CHUNK) * Q_CHUNK
-        self.hq = cfg["num_attention_heads"]
-        self.hkv = cfg["num_key_value_heads"]
-        self.hd = cfg["head_dim"]
         self.eps = float(cfg["rms_norm_eps"])
 
     # -- weights ---------------------------------------------------------------
     @partial(jax.jit, static_argnums=(0, 2))
     def _layer(self, layer, control: bool):
-        w = W.layer_weights(self.cfg, self.key, layer)
+        w = self.layer_weights(layer)
         w = {k: v.astype(jnp.float32) for k, v in w.items()}
         if control:
             w = {k: _fp8(v, 0) for k, v in w.items()}
@@ -76,62 +76,12 @@ class Reference:
             emb, head = _fp8(emb, 1), _fp8(head, 0)
         return emb, head
 
-    # -- pieces of a layer -------------------------------------------------------
     def _norm(self, x):
         return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.eps)
-
-    def _rope(self, x, pos):
-        hd = x.shape[-1]
-        rot = int(hd * self.cfg["partial_rotary_factor"]) // 2 * 2
-        half = rot // 2
-        freqs = 1.0 / (self.cfg["rope_theta"]
-                       ** (jnp.arange(half, dtype=jnp.float32) / half))
-        ang = pos[:, None, None].astype(jnp.float32) * freqs
-        x1, x2, rest = x[..., :half], x[..., half:rot], x[..., rot:]
-        c, s = jnp.cos(ang), jnp.sin(ang)
-        return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c, rest], -1)
 
     @staticmethod
     def _mm(x, w, control: bool):
         return (_fp8(x, -1) if control else x) @ w
-
-    @partial(jax.jit, static_argnums=(0, 3))
-    def _qkv(self, w, h, control: bool):
-        s = h.shape[0]
-        pos = jnp.arange(s, dtype=jnp.int32)
-        hn = self._norm(h)
-        mm = lambda name: self._mm(hn, w[name], control)
-        q = self._rope(mm("wq").reshape(s, self.hq, self.hd), pos)
-        k = self._rope(mm("wk").reshape(s, self.hkv, self.hd), pos)
-        v = mm("wv").reshape(s, self.hkv, self.hd)
-        return q, k, v
-
-    @partial(jax.jit, static_argnums=(0,))
-    def _attend_exact(self, q, k, v):
-        s = q.shape[0]
-        g = self.hq // self.hkv
-        scale = self.hd ** -0.5
-        cols = jnp.arange(s)
-
-        def chunk(i):
-            qc = jax.lax.dynamic_slice_in_dim(q, i * Q_CHUNK, Q_CHUNK, 0)
-            qc = qc.reshape(Q_CHUNK, self.hkv, g, self.hd) * scale
-            sc = jnp.einsum("chgd,khd->hgck", qc, k)
-            rows = i * Q_CHUNK + jnp.arange(Q_CHUNK)
-            sc = jnp.where(cols[None, :] <= rows[:, None], sc, -jnp.inf)
-            p = jax.nn.softmax(sc, axis=-1)
-            o = jnp.einsum("hgck,khd->chgd", p, v)
-            return o.reshape(Q_CHUNK, self.hq, self.hd)
-
-        return jax.lax.map(chunk, jnp.arange(s // Q_CHUNK)).reshape(
-            s, self.hq, self.hd)
-
-    @partial(jax.jit, static_argnums=(0, 4))
-    def _finish_layer(self, w, h, o, control: bool):
-        mm = lambda x, name: self._mm(x, w[name], control)
-        h = h + mm(o.reshape(h.shape[0], -1), "wo")
-        hn = self._norm(h)
-        return h + mm(jax.nn.silu(mm(hn, "w_gate")) * mm(hn, "w_up"), "w_down")
 
     @partial(jax.jit, static_argnums=(0,))
     def _embed(self, emb, tokens):
@@ -172,10 +122,8 @@ class Reference:
         del emb
         for layer in range(self.cfg["num_hidden_layers"]):
             w = self._layer(layer, control)
-            for r, sq in enumerate(seqs):
-                q, k, v = self._qkv(w, hs[r], control)
-                o = self._attend_exact(q, k, v)
-                hs[r] = self._finish_layer(w, hs[r], o, control)
+            for r in range(len(seqs)):
+                hs[r] = self.layer_forward(layer, w, hs[r], control)
             del w
         return hs
 

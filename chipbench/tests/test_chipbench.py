@@ -1,14 +1,18 @@
 """CPU tests of the chip benchmark: the trace reduction, the traffic
-generator, the readers' arithmetic, lookup by name, the refusal off the
-TPU, the work counts, and whole tiny runs in which ``correct`` holds for
-the program and fails for the control and for a broken timed path.
+generator, the readers' arithmetic, lookup by name (model kinds too), the
+refusal off the TPU, the work counts, the dense kind's weights and
+reference against pinned values, and whole tiny runs in which ``correct``
+holds for the program and fails for the control and for a broken timed
+path.
 
     JAX_PLATFORMS=cpu python3 -m pytest -q chipbench/tests
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -24,7 +28,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 from chipbench import harness, traffic  # noqa: E402
 from chipbench import tracereduce as TR  # noqa: E402
 from chipbench.readout import Req, Run, Tick, itl_samples, percentile  # noqa: E402
-from chipbench.workcount import Work  # noqa: E402
+from chipbench.references.dense_gqa import Work  # noqa: E402
 
 import tiny  # noqa: E402
 
@@ -191,6 +195,10 @@ def test_cells_configs_traffic_and_metrics_are_found_by_name():
         assert cell.mix == traffic.load_mix(w["traffic"])
         assert cell.end_to_end and cell.per_layer
         assert any(m["name"] == "setup_s" for m in cell.end_to_end)
+        assert (harness.REFERENCES
+                / f"{cell.config['reference']}.py").is_file()
+        for name in ("program_params", "Reference", "Work"):
+            assert hasattr(cell.kind, name)
         for m in cell.end_to_end + cell.per_layer:
             assert callable(harness.load_reader(m["name"]))
     with pytest.raises(KeyError):
@@ -217,6 +225,20 @@ def test_benchmark_file_keeps_to_its_shape():
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
 
 
+def test_unknown_model_kind_is_refused_before_any_weights(tmp_path):
+    bench = harness.load_benchmark()
+    cfg = json.loads((ROOT / bench["configs"][0]["file"]).read_text())
+    cfg["reference"] = "no_such_kind"
+    (tmp_path / "cfg.json").write_text(json.dumps(cfg))
+    bench = dict(bench, configs=[dict(bench["configs"][0],
+                                      file=str(tmp_path / "cfg.json"))])
+    w = bench["workloads"][0]
+    with pytest.raises(FileNotFoundError, match="references/no_such_kind.py"):
+        harness.find_cell(bench, w["name"])
+    with pytest.raises(FileNotFoundError, match="references/no_such_kind.py"):
+        harness.load_kind("no_such_kind")
+
+
 # -- refusal off the TPU -------------------------------------------------------
 
 def test_run_refuses_without_a_tpu():
@@ -231,7 +253,6 @@ def test_run_refuses_without_a_tpu():
 
 
 def test_run_refuses_without_the_program(tmp_path):
-    import shutil
     shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
     shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns(".out", "__pycache__"))
@@ -264,6 +285,48 @@ def test_work_counts_match_hand_numbers():
         2 * 32 * phi.layer_params() * 2 + 32 * 4 * 24 * 128 * 3)
 
 
+# -- the dense kind against the parent ------------------------------------------
+
+# Values computed at commit 58f588ecf0a4b7f87a264875c49ef5468328d35d, where
+# weights.py, reference.py and workcount.py held the dense GQA layer, for
+# the tiny configuration and seed 2**33 + 5: the sha256 of the program's
+# parameter leaves (sorted by path, each path then its bytes), and the
+# reference's served and fp8 control gaps on the two sequences below.
+PARENT_PARAMS_SHA256 = (
+    "569b818b43e7ecb4cf335be467760a5c1e184bc1f4058c7bdfbdea69e5c27db3")
+PARENT_SERVED_GAPS = [
+    [1.3762218952178955, 5.728619575500488, 1.3330061435699463,
+     3.188261032104492, 2.2053580284118652, 3.062866449356079],
+    [5.9397101402282715, 4.235085487365723, 2.4417953491210938,
+     3.624140501022339, 4.349161624908447]]
+PARENT_CONTROL_GAPS = [
+    [0.034507036209106445, 0.0, 0.0, 0.0, 0.0, 0.0],
+    [0.0, 0.024850130081176758, 0.0, 0.0, 0.0]]
+
+
+def test_dense_kind_weights_and_reference_match_the_parent():
+    import jax
+    from chipbench.reference import Sequence
+    from chipbench.references import dense_gqa
+    cfg, seed = tiny.tiny_config(), 2 ** 33 + 5
+    leaves = jax.tree_util.tree_flatten_with_path(
+        dense_gqa.program_params(cfg, seed))[0]
+    h = hashlib.sha256()
+    for path, leaf in sorted(leaves,
+                             key=lambda t: jax.tree_util.keystr(t[0])):
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == PARENT_PARAMS_SHA256
+    seqs = [Sequence(np.arange(40, dtype=np.int32) * 37 % 8192,
+                     np.array([5, 901, 77, 4000, 12, 8191], np.int32)),
+            Sequence(np.arange(70, dtype=np.int32) * 113 % 8192,
+                     np.array([3, 2, 1, 7000, 555], np.int32))]
+    served, ctl = dense_gqa.Reference(cfg, 256, seed).gaps(seqs, control=True)
+    want = PARENT_SERVED_GAPS + PARENT_CONTROL_GAPS
+    for got, w in zip(served + ctl, want):
+        np.testing.assert_allclose(got, w, rtol=0, atol=1e-6)
+
+
 # -- whole tiny runs on the CPU -------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -285,6 +348,19 @@ def test_tiny_run_is_correct(tiny_arch):
     assert out.diagnostics["window_compiles"]["backend_compiles"] == 0
     for name, limit in TINY_LIMITS.items():
         assert 0 <= out.compared[name]["value"] <= limit
+
+
+def test_a_copied_kind_module_runs_the_tiny_cell(tiny_arch, tmp_path,
+                                                monkeypatch):
+    """A new model kind is one file under references/ and nothing else."""
+    shutil.copy(harness.REFERENCES / "dense_gqa.py",
+                tmp_path / "dense_gqa_copy.py")
+    monkeypatch.setattr(harness, "REFERENCES", tmp_path)
+    cell = tiny.tiny_cell("closed", TINY_LIMITS, reference="dense_gqa_copy")
+    assert cell.kind.__file__ == str(tmp_path / "dense_gqa_copy.py")
+    out = harness.run_cell(cell, 2 ** 33 + 8, 3.0, False, time.perf_counter())
+    assert out.correct, out.compared
+    assert out.failed == 0 and out.attempted > 0
 
 
 def test_cell_without_limits_is_not_correct(tiny_arch):

@@ -45,10 +45,13 @@ def register_tiny_arch():
                            head_dim=64, rope_theta=10000.0)
 
 
-def tiny_cell(loop: str = "closed", limits=None):
+def tiny_cell(loop: str = "closed", limits=None,
+              reference: str = "dense_gqa"):
     from chipbench import harness
     bench = harness.load_benchmark()
-    return harness.Cell(name="tiny", chips=1, config=tiny_config(),
+    config = dict(tiny_config(), reference=reference)
+    return harness.Cell(name="tiny", chips=1, config=config,
+                        kind=harness.load_kind(reference),
                         mix=tiny_mix(loop), end_to_end=bench["end_to_end"],
                         per_layer=bench["per_layer"],
                         limits={"limits": dict(limits or {})})
