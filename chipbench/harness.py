@@ -30,6 +30,7 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 OUT = BENCH / ".out"
 REFERENCES = BENCH / "references"
+METRICS = BENCH / "metrics"
 MAX_WARM_TICKS = 400
 
 
@@ -87,7 +88,7 @@ def _load(path: Path, what: str, package: str, name: str) -> ModuleType:
 
 
 def load_reader(metric: str) -> Callable[[Run], Optional[float]]:
-    return _load(BENCH / "metrics" / f"{metric}.py", "reader for metric",
+    return _load(METRICS / f"{metric}.py", "reader for metric",
                  "metrics", metric).read
 
 
@@ -186,7 +187,6 @@ class Client:
         self.done: List[Req] = []
         self.all: List[Req] = []
         self.ticks: List[Tick] = []
-        self.kinds: List[str] = []   # dispatch kinds, in order
         self.annotate = annotate
         self.lateness: List[float] = []
         self.closed = mix["loop"] == "closed"
@@ -230,8 +230,6 @@ class Client:
         with self.annotate("observe"):
             tick = self._observe(s0, before, t_start, t_end)
         self.ticks.append(tick)
-        self.kinds += (["prefill"] * tick.prefill_dispatches
-                       + ["decode"] * tick.decode_dispatches)
         return tick
 
     def _observe(self, s0, before, t_start, t_end) -> Tick:
@@ -312,6 +310,7 @@ class Outcome:
     breakdown: Optional[dict]
     compared: Dict[str, dict]
     diagnostics: dict
+    run: Run
 
 
 def warm_up(client: Client, mix: dict, vocab: int, seed: int) -> None:
@@ -404,29 +403,33 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(str(trace_dir))
-    n_ticks0, n_kinds0 = len(client.ticks), len(client.kinds)
+    n_ticks0 = len(client.ticks)
     m0 = meter.snapshot()
     late0 = len(client.lateness)
+    stats0 = dict(engine.stats)
     t0 = time.perf_counter()
     with annotate("window"):
         client.serve_until(t0 + seconds)
         jax.block_until_ready(engine.cache)
     t1 = t0 + seconds
     m1 = meter.snapshot()
+    counters = {k: v - stats0[k] for k, v in engine.stats.items()
+                if isinstance(v, (int, float)) and k in stats0}
     mem = dev.memory_stats() or {}
     reduced = None
     if trace:
         jax.profiler.stop_trace()
-        events = TR.read_xplane(TR.newest_xplane(trace_dir))
-        reduced = TR.reduce(events, client.kinds[n_kinds0:])
+        events = TR.read(TR.newest_xplane(trace_dir))
+        reduced = TR.reduce(events)
         trace_summary = TR.summary(events)
+        del events
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    window_ticks = [t for t in client.ticks[n_ticks0:] if t.start < t1]
+    window_ticks = client.ticks[n_ticks0:]   # as the counters: the window's loop
     run = Run(t0=t0, t1=t1, setup_s=t0 - t_process, loop=mix["loop"],
               requests=client.all, ticks=window_ticks,
               work=cell.kind.Work(cell.config, int(mix["max_len"])),
-              peaks=peaks, trace=reduced)
+              peaks=peaks, trace=reduced, counters=counters)
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in wanted:
@@ -450,13 +453,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         "memory_stats": mem,
         "engine_stats": dict(engine.stats),
         "window_ticks": len(window_ticks),
-        "window_dispatches": len(client.kinds) - n_kinds0,
+        "window_dispatches": (counters["prefill_dispatches"]
+                              + counters["decode_dispatches"]),
         "finished_in_window": len(window_done),
         "in_flight_at_close": engine.in_flight,
     }
     if reduced is not None:
-        diag["trace_matched"] = reduced.matched
-        diag["trace"] = trace_summary
+        diag["trace"] = dict(trace_summary, covered_s=reduced.covered_s,
+                             cut_s=reduced.cut_s)
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
@@ -495,7 +499,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                            for c in compared.values()))
     return Outcome(correct=correct, attempted=len(sent_in), failed=len(bad),
                    metrics=metrics, device=device, breakdown=breakdown,
-                   compared=compared, diagnostics=diag)
+                   compared=compared, diagnostics=diag, run=run)
 
 
 def _ms(x: Optional[float]) -> Optional[float]:

@@ -2,17 +2,24 @@
 
 All times are seconds on the client's host clock (``time.perf_counter``).
 A reader (``metrics/<name>.py``) gets a :class:`Run` and returns a number,
-or None where the run holds nothing for it to read.
+or None where the run holds nothing for it to read. Besides what the
+client saw, a run holds the engine's counters over the window
+(``counters``: the change of every numeric key of ``engine.stats``) and,
+in a traced run, the reduced trace (``trace``: device seconds per
+program and per op, the engine's ``serve.*`` spans with their counts,
+seconds, device idle and summed args, and how much of the window the
+device trace covers), so a reader of a new program, span or counter name
+is one new file.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from chipbench.tracereduce import Reduced
+from chipbench.tracereduce import Trace
 
 
 @dataclasses.dataclass
@@ -49,10 +56,11 @@ class Run:
     setup_s: float
     loop: str
     requests: List[Req]
-    ticks: List[Tick]           # the ticks that started inside the window
+    ticks: List[Tick]           # the ticks the window's loop ran
     work: Any                   # the model kind's Work (references/<kind>.py)
     peaks: dict
-    trace: Optional[Reduced] = None
+    trace: Optional[Trace] = None
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
     def seconds(self) -> float:
@@ -61,10 +69,15 @@ class Run:
     def in_window(self, t: float) -> bool:
         return self.t0 <= t <= self.t1
 
-    def program_seconds(self, kind: str) -> Optional[float]:
-        if self.trace is None or not self.trace.matched:
+    def program_seconds(self, prefix: str) -> Optional[float]:
+        """Device seconds of the programs whose names start with
+        ``prefix``, where the device trace covers the window; None where
+        no such program ran there."""
+        if self.trace is None:
             return None
-        return sum(s for k, s in self.trace.programs if k == kind)
+        got = [p["device_s"] for name, p in self.trace.programs.items()
+               if name.startswith(prefix)]
+        return sum(got) if got else None
 
 
 def percentile(values: Sequence[float], q: float) -> Optional[float]:

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import List
+from typing import Hashable, List
 
 import jax
 import jax.numpy as jnp
@@ -45,9 +45,11 @@ def _fp8(x: jax.Array, axis: int) -> jax.Array:
 
 class Reference:
     """A kind (``references/<kind>.py``) subclasses it with its layer:
-    ``layer_weights(layer)`` draws one layer's weights (``layer`` traced),
-    ``layer_forward(layer, w, h, control)`` runs it over one request's
-    hidden states [s_pad, d]."""
+    ``layer_weights(layer, group)`` draws one layer's weights (``layer``
+    traced, ``group`` static), ``layer_forward(layer, w, h, control)`` runs
+    it over one request's hidden states [s_pad, d]. A kind whose layers
+    differ in their weights' shapes (dense layers before expert layers, or
+    another head geometry) names each layer's group in ``layer_group``."""
 
     def __init__(self, cfg: dict, ring: int, seed: int):
         if cfg.get("a3"):
@@ -59,9 +61,15 @@ class Reference:
         self.eps = float(cfg["rms_norm_eps"])
 
     # -- weights ---------------------------------------------------------------
-    @partial(jax.jit, static_argnums=(0, 2))
-    def _layer(self, layer, control: bool):
-        w = self.layer_weights(layer)
+    def layer_group(self, layer: int) -> Hashable:
+        """The group of ``layer``: the layers of one group have weights of
+        the same shapes, drawn by one compiled program. One group unless a
+        kind says otherwise."""
+        return 0
+
+    @partial(jax.jit, static_argnums=(0, 2, 3))
+    def _layer(self, layer, group, control: bool):
+        w = self.layer_weights(layer, group)
         w = {k: v.astype(jnp.float32) for k, v in w.items()}
         if control:
             w = {k: _fp8(v, 0) for k, v in w.items()}
@@ -121,7 +129,7 @@ class Reference:
         hs = [self._embed(emb, t) for t in toks]
         del emb
         for layer in range(self.cfg["num_hidden_layers"]):
-            w = self._layer(layer, control)
+            w = self._layer(layer, self.layer_group(layer), control)
             for r in range(len(seqs)):
                 hs[r] = self.layer_forward(layer, w, hs[r], control)
             del w

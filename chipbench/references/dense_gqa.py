@@ -65,7 +65,7 @@ class Reference(R.Reference):
         self.hkv = cfg["num_key_value_heads"]
         self.hd = cfg["head_dim"]
 
-    def layer_weights(self, layer) -> dict:
+    def layer_weights(self, layer, group) -> dict:
         return layer_weights(self.cfg, self.key, layer)
 
     def layer_forward(self, layer: int, w: dict, h, control: bool):

@@ -40,53 +40,85 @@ TINY_LIMITS = {"logit_gap": 0.05, "mean_logit_gap": 0.002}
 
 # -- trace reduction ----------------------------------------------------------
 
-def _ev(name, start, dur):
-    return TR.Event(name, float(start), float(dur))
+def _ev(name, start, dur, **args):
+    return TR.Event(name, float(start), float(dur), args or None)
 
 
 def test_trace_reduction_matches_programs_and_idle():
+    """Ops are labelled by the program whose module holds them, read from
+    the module's name; idle gaps by the harness's host span."""
     dev = "/device:TPU:0"
-    modules = [_ev("jit_step(7)", 100, 50), _ev("jit_step(9)", 160, 20),
-               _ev("jit_convert", 185, 2), _ev("jit_step(9)", 300, 20)]
+    modules = [_ev("jit_prefill_chunk(7)", 100, 50),
+               _ev("jit_decode_block(9)", 160, 20),
+               _ev("jit_convert", 185, 2), _ev("jit_decode_block(9)", 300, 20)]
     ops = [_ev("fusion.1", 100, 30), _ev("fusion.2", 130, 20),
            _ev("fusion.3", 160, 20), _ev("copy", 185, 2),
            _ev("fusion.3", 300, 20), _ev("late", 450, 100)]
     host = [_ev("chipbench.window", 90, 310), _ev("chipbench.step", 95, 100),
             _ev("chipbench.observe", 195, 10), _ev("chipbench.wait", 205, 90),
             _ev("chipbench.step", 295, 30)]
-    ev = TR.TraceEvents({dev: modules}, {dev: ops}, host)
-    red = TR.reduce(ev, ["prefill", "decode", "decode"])
-    assert red.matched
-    assert red.programs == [("prefill", 50e-9), ("decode", 20e-9),
-                            ("decode", 20e-9)]
-    assert red.window_s == pytest.approx(310e-9)
+    tr = TR.reduce(TR.TraceEvents({dev: modules}, {dev: ops}, host))
+    assert tr.programs == {
+        "jit_prefill_chunk": {"device_s": pytest.approx(50e-9), "modules": 1,
+                              "idle_s": 0.0},
+        "jit_decode_block": {"device_s": pytest.approx(40e-9), "modules": 2,
+                             "idle_s": 0.0},
+        "jit_convert": {"device_s": pytest.approx(2e-9), "modules": 1,
+                        "idle_s": 0.0}}
+    assert tr.window_s == pytest.approx(310e-9)
     # busy: [100,150) + [160,180) + [185,187) + [300,320) in [90, 400)
-    assert red.busy_s == pytest.approx(92e-9)
+    assert tr.busy_s == pytest.approx(92e-9)
     # each idle gap goes to the host span that holds its midpoint
-    idle = dict(red.idle_gaps)
+    idle = dict(tr.idle_gaps)
     assert idle["step"] == pytest.approx(25e-9)       # 90-100, 150-160, 180-185
     assert idle["wait"] == pytest.approx(113e-9)      # 187-300
     assert idle["other"] == pytest.approx(80e-9)      # 320-400
     assert "observe" not in idle
-    ops_by = dict(red.device_ops)
-    assert ops_by["decode:fusion.3"] == pytest.approx(40e-9)
-    assert ops_by["prefill:fusion.1"] == pytest.approx(30e-9)
-    assert "other:late" not in ops_by                            # after the window
+    ops_by = dict(tr.device_ops)
+    assert ops_by["jit_decode_block:fusion.3"] == pytest.approx(40e-9)
+    assert ops_by["jit_prefill_chunk:fusion.1"] == pytest.approx(30e-9)
+    assert ops_by["jit_convert:copy"] == pytest.approx(2e-9)
+    assert not any(k.startswith("other:") for k in ops_by)
+    assert "late" not in tr.ops                                  # after the window
 
 
 def test_trace_reduction_refuses_to_guess_on_mismatch():
+    """An op that lies in no module event is labelled ``other``, never
+    charged to the program that ran before or after it; a module name
+    that is not a program name's characters stays whole."""
     dev = "/device:TPU:0"
-    ev = TR.TraceEvents({dev: [_ev("jit_step", 10, 5)]}, {},
+    ev = TR.TraceEvents({dev: [_ev("jit_decode_block(3)", 10, 5),
+                               _ev("jit_decode_block(3)", 40, 5)]},
+                        {dev: [_ev("fusion", 10, 5), _ev("stray", 20, 5),
+                               _ev("fusion", 40, 5)]},
                         [_ev("chipbench.window", 0, 100)])
-    red = TR.reduce(ev, ["prefill", "decode"])
-    assert not red.matched and red.programs == []
-    assert red.busy_s == pytest.approx(5e-9)
+    tr = TR.reduce(ev)
+    assert dict(tr.device_ops) == {"jit_decode_block:fusion": pytest.approx(10e-9),
+                                   "other:stray": pytest.approx(5e-9)}
+    assert tr.programs["jit_decode_block"]["device_s"] == pytest.approx(10e-9)
+    assert tr.busy_s == pytest.approx(15e-9)
+    assert TR.program_of("jit_prefill_chunk_nosort(12)") == \
+        "jit_prefill_chunk_nosort"
+    assert TR.program_of("(odd)") == "(odd)"
 
 
 def test_union_and_gaps():
     u = TR.union([(5, 10), (0, 3), (2, 4), (9, 12)], 1, 11)
     assert u == [(1, 4), (5, 11)]
     assert TR.gaps(u, 0, 20) == [(0, 1), (4, 5), (11, 20)]
+
+
+def test_measure_of_intervals_inside_a_span_matches_a_plain_sum():
+    rng = np.random.default_rng(3)
+    edges = np.sort(rng.choice(1000, size=200, replace=False)).astype(float)
+    intervals = list(zip(edges[::2], edges[1::2]))
+    m = TR.Measure(intervals)
+    assert m.total() == pytest.approx(sum(e - s for s, e in intervals))
+    for a, b in rng.uniform(-50, 1050, size=(300, 2)):
+        a, b = min(a, b), max(a, b)
+        plain = sum(max(0.0, min(e, b) - max(s, a)) for s, e in intervals)
+        assert m.within(a, b) == pytest.approx(plain, abs=1e-9)
+    assert m.within(5, 5) == 0 and TR.Measure([]).within(0, 10) == 0
 
 
 # -- traffic -------------------------------------------------------------------
@@ -163,14 +195,25 @@ def test_scheduler_and_device_readers():
              Tick(10.2, 10.3, 0, 1, [], 1, 1, [303, 41])]
     run = _run([], ticks)
     assert harness.load_reader("decode_occupancy")(run) == pytest.approx(5 / 3)
-    run.trace = TR.Reduced(window_s=0.5, busy_s=0.4,
-                           programs=[("prefill", 0.2), ("decode", 0.02),
-                                     ("decode", 0.02), ("decode", 0.02)],
-                           matched=True, device_ops=[], idle_gaps=[])
-    assert harness.load_reader("decode_step_ms")(run) == pytest.approx(20.0)
-    assert harness.load_reader("prefill_ms_per_ktok")(run) == pytest.approx(
-        200.0 / 0.3)
-    assert harness.load_reader("device_idle_share")(run) == pytest.approx(20.0)
+    dev = "/device:TPU:0"
+    mods = [_ev("jit_prefill_chunk(2)", 0, 2e8),
+            _ev("jit_decode_block(5)", 2e8, 2e7),
+            _ev("jit_decode_block(5)", 2.2e8, 2e7),
+            _ev("jit_decode_block(5)", 2.4e8, 2e7)]
+    host = [_ev("chipbench.window", 0, 5e8),
+            _ev("serve.dispatch.prefill", 0, 1e3, tokens=300, positions=512),
+            *[_ev("serve.dispatch.decode", t, 1e3, steps=1)
+              for t in (2e8, 2.2e8, 2.4e8)],
+            _ev("serve.tick", 0, 4e8)]
+    run.trace = TR.reduce(TR.TraceEvents({dev: mods}, {}, host))
+    run.t0 = 10.0
+    assert harness.load_reader("decode_program_ms")(run) == pytest.approx(20.0)
+    assert harness.load_reader("prefill_program_ms_per_ktok")(run) == \
+        pytest.approx(200.0 / 0.3)
+    assert harness.load_reader("device_idle_share")(run) == \
+        pytest.approx(100 * (1 - 0.26 / 0.5))
+    assert harness.load_reader("step_idle_share")(run) == \
+        pytest.approx(100 * 0.14 / 0.5)
     roof = harness.load_reader("decode_roofline")(run)
     w = run.work
     least = sum(w.decode_step_least_s(1, t.decode_keys, run.peaks)
@@ -181,8 +224,43 @@ def test_scheduler_and_device_readers():
     flops = w.prefill_flops(0, 300, True) + sum(
         w.decode_lane_flops(k) for t in ticks for k in t.decode_keys)
     assert mfu == pytest.approx(100 * flops / 0.5 / 197e12)
+    run.counters = {"prefill_tokens": 300, "prefill_positions": 512}
+    assert harness.load_reader("prefill_padding_share")(run) == \
+        pytest.approx(100 * (1 - 300 / 512))
     run.trace = None
-    assert harness.load_reader("decode_step_ms")(run) is None
+    for name in ("decode_program_ms", "decode_roofline", "step_idle_share"):
+        assert harness.load_reader(name)(run) is None
+
+
+ROUTER_READER = '''"""Made-up reader of names the harness has never heard of."""
+
+
+def read(run):
+    sec = run.program_seconds("jit_route_tokens")
+    spans = run.trace.spans.get("serve.dispatch.route")
+    if sec is None or not spans:
+        return None
+    return (sec * 1e3 / spans["args"]["experts"]
+            + run.counters["tokens_routed"] / spans["count"])
+'''
+
+
+def test_a_reader_in_a_new_file_reads_new_program_span_and_counter(
+        tmp_path, monkeypatch):
+    """A new model's program, span and counter reach a reader that is one
+    new file under metrics/, with no edit to the harness."""
+    (tmp_path / "route_ms_per_expert.py").write_text(ROUTER_READER)
+    monkeypatch.setattr(harness, "METRICS", tmp_path)
+    dev = "/device:TPU:0"
+    mods = [_ev("jit_route_tokens(4)", 10, 30), _ev("jit_route_tokens(4)", 50, 10)]
+    host = [_ev("chipbench.window", 0, 100),
+            _ev("serve.dispatch.route", 9, 1, experts=8, lanes=4),
+            _ev("serve.dispatch.route", 49, 1, experts=4, lanes=4)]
+    run = _run([])
+    run.trace = TR.reduce(TR.TraceEvents({dev: mods}, {}, host))
+    run.counters = {"tokens_routed": 64}
+    read = harness.load_reader("route_ms_per_expert")
+    assert read(run) == pytest.approx(40e-9 * 1e3 / 12 + 64 / 2)
 
 
 # -- lookup by name ------------------------------------------------------------
@@ -304,9 +382,16 @@ PARENT_CONTROL_GAPS = [
     [0.0, 0.024850130081176758, 0.0, 0.0, 0.0]]
 
 
+def _pinned_seqs():
+    from chipbench.reference import Sequence
+    return [Sequence(np.arange(40, dtype=np.int32) * 37 % 8192,
+                     np.array([5, 901, 77, 4000, 12, 8191], np.int32)),
+            Sequence(np.arange(70, dtype=np.int32) * 113 % 8192,
+                     np.array([3, 2, 1, 7000, 555], np.int32))]
+
+
 def test_dense_kind_weights_and_reference_match_the_parent():
     import jax
-    from chipbench.reference import Sequence
     from chipbench.references import dense_gqa
     cfg, seed = tiny.tiny_config(), 2 ** 33 + 5
     leaves = jax.tree_util.tree_flatten_with_path(
@@ -317,14 +402,60 @@ def test_dense_kind_weights_and_reference_match_the_parent():
         h.update(jax.tree_util.keystr(path).encode())
         h.update(np.asarray(leaf).tobytes())
     assert h.hexdigest() == PARENT_PARAMS_SHA256
-    seqs = [Sequence(np.arange(40, dtype=np.int32) * 37 % 8192,
-                     np.array([5, 901, 77, 4000, 12, 8191], np.int32)),
-            Sequence(np.arange(70, dtype=np.int32) * 113 % 8192,
-                     np.array([3, 2, 1, 7000, 555], np.int32))]
-    served, ctl = dense_gqa.Reference(cfg, 256, seed).gaps(seqs, control=True)
+    served, ctl = dense_gqa.Reference(cfg, 256, seed).gaps(_pinned_seqs(),
+                                                           control=True)
     want = PARENT_SERVED_GAPS + PARENT_CONTROL_GAPS
     for got, w in zip(served + ctl, want):
         np.testing.assert_allclose(got, w, rtol=0, atol=1e-6)
+
+
+TWO_GROUPS_KIND = '''"""Toy kind: dense GQA layers, the first two of
+them in a group of their own whose FFN is WIDE times as wide."""
+from chipbench.references import dense_gqa
+
+WIDE = {wide}
+
+
+class Reference(dense_gqa.Reference):
+    def layer_group(self, layer):
+        return "lead" if layer < 2 else "rest"
+
+    def layer_weights(self, layer, group):
+        cfg = self.cfg
+        if group == "lead":
+            cfg = dict(cfg, intermediate_size=WIDE * cfg["intermediate_size"])
+        return dense_gqa.layer_weights(cfg, self.key, layer)
+'''
+
+
+@pytest.mark.parametrize("wide", [1, 2])
+def test_a_kind_with_two_layer_groups_runs_to_gaps(tmp_path, monkeypatch,
+                                                   wide):
+    """A kind whose layers differ in their weights' shapes is one new file
+    under references/: each group compiles its own weights program. With
+    the groups alike (``wide`` 1) it is the dense kind to the pinned
+    gaps."""
+    from chipbench import reference
+    (tmp_path / "two_groups.py").write_text(TWO_GROUPS_KIND.format(wide=wide))
+    monkeypatch.setattr(harness, "REFERENCES", tmp_path)
+    kind = harness.load_kind("two_groups")
+    cfg, seed = tiny.tiny_config(), 2 ** 33 + 5
+    ref = kind.Reference(cfg, 256, seed)
+    shapes = [ref.layer_weights(i, ref.layer_group(i))["w_up"].shape
+              for i in range(cfg["num_hidden_layers"])]
+    assert shapes == [(256, 512 * wide)] * 2 + [(256, 512)] * 2
+    before = reference.Reference._layer._cache_size()
+    served, ctl = ref.gaps(_pinned_seqs(), control=True)
+    # two groups, each as itself and as the fp8 control
+    assert reference.Reference._layer._cache_size() - before == 4
+    assert [len(g) for g in served] == [6, 5] == [len(g) for g in ctl]
+    assert all(np.isfinite(g).all() and (g >= 0).all() for g in served + ctl)
+    pinned = PARENT_SERVED_GAPS + PARENT_CONTROL_GAPS
+    diff = max(np.abs(g - w).max() for g, w in zip(served + ctl, pinned))
+    if wide == 1:
+        assert diff <= 1e-6
+    else:
+        assert diff > 1e-3
 
 
 # -- whole tiny runs on the CPU -------------------------------------------------
