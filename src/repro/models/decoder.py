@@ -404,6 +404,17 @@ def decode_step(
     the per-layer (candidate count, captured-score-mass ratio) leaves
     summed over every probed layer, for telemetry sampling. The logits
     and cache are computed by the identical ops either way.
+
+    How the layer scan threads a segment's cache leaves follows their
+    role. Mutable leaves (K/V rings, recurrent states) ride the scan
+    *carry*: each layer reads its slice at the layer index and writes
+    the mixer's new slice back in place, so a donated cache updates in
+    its own buffers. Scanned as xs -> ys they would land in fresh
+    whole-leaf ys buffers, copied back into the donated buffers after
+    the loop (two whole-cache copies a step). Read-only leaves
+    (``sk_*``, ``sorted_upto``) are scanned as xs and never returned;
+    a mixer's per-layer outputs that are not cache state (``_probe``)
+    are the scan's ys.
     """
     if input_embed is not None:
         h = input_embed[:, None, :].astype(jnp.dtype(cfg.dtype))
@@ -419,15 +430,22 @@ def decode_step(
         mut = {k: v for k, v in seg_cache.items() if k not in _RO}
 
         def body(carry, xs):
-            lp, cs, ro_s = xs
-            out, ns, aux = _decode_block(lp, {**cs, **ro_s}, carry, pos,
+            x, layer, state = carry
+            lp, ro_s = xs
+            cs = {k: jax.lax.dynamic_index_in_dim(v, layer, 0, False)
+                  for k, v in state.items()}
+            out, ns, aux = _decode_block(lp, {**cs, **ro_s}, x, pos,
                                          cfg, seg, a3, use_kernel,
                                          probe=probe)
-            return out, ns
+            state = {k: jax.lax.dynamic_update_index_in_dim(
+                         v, ns[k], layer, 0) for k, v in state.items()}
+            per_layer = {k: v for k, v in ns.items() if k not in state}
+            return (out, layer + 1, state), per_layer
 
-        h, new_seg = jax.lax.scan(body, h, (params[f"seg{si}"], mut, ro))
-        if probe and "_probe" in new_seg:
-            pr = new_seg.pop("_probe")           # [L_seg, B, 2]
+        (h, _, new_seg), per_layer = jax.lax.scan(
+            body, (h, jnp.int32(0), mut), (params[f"seg{si}"], ro))
+        if probe and "_probe" in per_layer:
+            pr = per_layer["_probe"]             # [L_seg, B, 2]
             probe_sum = probe_sum + pr.sum(axis=0) if probe_sum is not None \
                 else pr.sum(axis=0)
             probe_layers += pr.shape[0]

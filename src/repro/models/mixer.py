@@ -372,7 +372,9 @@ def _attn_decode_step(lp: Params, state: Dict[str, jax.Array],
     use_a3 = a3.mode != A3Mode.OFF and seg.window >= FULL_WINDOW
     # NOTE: read-only leaves (sk_*, sorted_upto) are NOT returned — the
     # caller keeps them out of the scan ys (passing them through forced
-    # a full copy of the sorted-key cache per layer iteration).
+    # a full copy of the sorted-key cache per layer iteration). The
+    # returned k/v ride the caller's scan carry and are written back into
+    # the donated ring in place (``decoder.decode_step``).
     new_state = {"k": kc, "v": vc}
     if use_a3 and "sk_vals" in state:
         # comprehension-time sorted keys cached at prefill (paper SSIV-C);
@@ -386,10 +388,10 @@ def _attn_decode_step(lp: Params, state: Dict[str, jax.Array],
                         rows=shard_act(state["sk_rows"], "kv_cache"))
         if probe:
             # A^3 quality probe (telemetry): captured-score-mass and
-            # candidate-count leaves ride the scan ys like any other
-            # mutable state and land with the ring harvest — zero
-            # extra host syncs. The attention output ops are identical
-            # with or without the probe.
+            # candidate-count leaves are not cache state, so they ride
+            # the scan ys (not the carry) and land with the ring
+            # harvest — zero extra host syncs. The attention output ops
+            # are identical with or without the probe.
             o, pr = a3_decode_attention_compact(
                 q[:, :, 0], kc, vc, valid, a3, sk, fresh_mask=fresh,
                 return_probe=True)
